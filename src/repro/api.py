@@ -633,10 +633,10 @@ class Session:
             # internal assignment (same convention as the measure
             # worker) — the emitted collectives are then attributable
             # to the plan rather than to free GSPMD propagation
-            from repro.launch.mesh import compat_make_mesh, mesh_context
+            from repro.launch.mesh import make_mesh
             from repro.models.sharding import logical_rules
-            mesh = compat_make_mesh(plan.mesh.sizes, plan.mesh.axes)
-            with mesh_context(mesh), \
+            mesh = make_mesh(plan.mesh.sizes, plan.mesh.axes)
+            with jax.set_mesh(mesh), \
                     logical_rules(plan.logical_rules or None):
                 lowered = plan.apply(self.fn, mesh).lower(*self.args)
             text = lowered.compile().as_text()

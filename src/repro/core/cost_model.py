@@ -711,8 +711,8 @@ class CostModel:
         blocked roles cannot enter the kernel, so the executor gathers
         those operands first — priced here as an all_gather and a
         full-size role.  A Pallas choice whose local shapes cannot tile
-        (``registry.MIN_BLOCK``) is priced as the reference impl, exactly
-        mirroring the execution-side fallback in ``kernels.ops``.
+        (``registry.MIN_BLOCK``) is priced as the reference impl, which
+        is what ``kernel_site_records`` then records for the site.
         """
         op, trip, uses, reshard, outs, opnb, resnb = self._op_specs[op_idx]
         impl = (kernel_impls or {}).get(op_idx, spec.default_impl)

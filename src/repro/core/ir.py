@@ -2,7 +2,7 @@
 
 The paper's NDA operates on straight-line tensor programs in ANF (SSA).
 A jaxpr is exactly that.  We extract a flat ``Program`` of ``Op`` nodes over
-integer value ids, inlining call-like sub-jaxprs (pjit, custom_jvp/vjp,
+integer value ids, inlining call-like sub-jaxprs (jit, custom_jvp/vjp,
 remat) and instantiating ``scan``/``while`` bodies once with explicit
 carry-in/carry-out connections (see nda.py for how those connections become
 identities).
@@ -87,10 +87,12 @@ class Program:
         self.ops.append(op)
 
 
+# the name the installed JAX gives ``jax.jit`` call sites in a jaxpr
+JIT_PRIM = "jit"
+
+# call-like primitives whose sub-jaxpr the extractor inlines
 _CALL_PRIMS = {
-    "pjit", "closed_call", "custom_jvp_call", "custom_vjp_call",
-    "custom_vjp_call_jaxpr", "remat", "remat2", "checkpoint", "core_call",
-    "xla_call", "sharding_constraint_call", "jit",
+    JIT_PRIM, "closed_call", "custom_jvp_call", "custom_vjp_call", "remat2",
 }
 
 
@@ -117,13 +119,13 @@ def _kernel_eqn_info(eqn):
     rather than producing a malformed fused op: results must match the
     registry arity exactly, operands must be at least it — grad-time
     partial evaluation *appends* hoisted loop-invariant values to a
-    pjit's invars (and can emit constant-only pjits reusing the name),
+    jit's invars (and can emit constant-only jits reusing the name),
     so the real operands are the leading ``n_operands`` invars, which
     must also have the registry ranks.  Implementation
     choice (pallas vs ref) is deliberately *not* part of the name — the
     traced program, and hence the fingerprint, is impl-independent.
     """
-    if eqn.primitive.name != "pjit":
+    if eqn.primitive.name != JIT_PRIM:
         return None
     name = eqn.params.get("name", "")
     if not isinstance(name, str) or not name.startswith(_KERNEL_JIT_PREFIX):

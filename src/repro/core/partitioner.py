@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import math
 import time
 from collections import Counter, defaultdict
 from typing import Callable
@@ -296,8 +297,8 @@ class ShardingPlan:
             or AOT-compile via its ``lower`` method.
         """
         if mesh is None:
-            from repro.launch.mesh import compat_make_mesh
-            mesh = compat_make_mesh(self.mesh.sizes, self.mesh.axes)
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh(self.mesh.sizes, self.mesh.axes)
         return AppliedPlan(self, fn, mesh, jit_kwargs)
 
     def as_dict(self) -> dict:
@@ -641,15 +642,17 @@ def kernel_site_records(cm: CostModel,
     counters: Counter = Counter()
     records: list[dict] = []
 
-    def _project(roles, vid, mappable):
+    def _project(roles, vid, mappable, dims):
         axes = cm.site_axes(cm.nda.def_site[vid], color_axes, suppressed)
         entries, sharded = [], False
-        for role, a in zip(roles, axes):
+        for role, a, n in zip(roles, axes, cm.prog.types[vid].shape):
             if role in mappable and a:
                 entries.append(a[0] if len(a) == 1 else tuple(a))
                 sharded = True
+                n //= math.prod(cm._axis_size[x] for x in a)
             else:
                 entries.append(None)
+            dims.setdefault(role, int(n))
         return PartitionSpec(*entries), sharded
 
     for op_idx, op in enumerate(cm.prog.ops):
@@ -658,19 +661,24 @@ def kernel_site_records(cm: CostModel,
             continue
         ordinal = counters[spec.name]
         counters[spec.name] += 1
-        in_specs, out_specs, sharded = [], [], False
+        in_specs, out_specs, sharded, dims = [], [], False, {}
         for roles, vid in zip(spec.operand_roles, op.operands):
-            ps, sh = _project(roles, vid, spec.mappable)
+            ps, sh = _project(roles, vid, spec.mappable, dims)
             in_specs.append(ps)
             sharded = sharded or sh
         for roles, vid in zip(spec.result_roles, op.results):
-            ps, sh = _project(roles, vid, spec.mappable)
+            ps, sh = _project(roles, vid, spec.mappable, dims)
             out_specs.append(ps)
             sharded = sharded or sh
+        impl = impls.get(op_idx, spec.default_impl)
+        if not spec.feasible(impl, dims):
+            # priced as the reference by the cost model; recorded so, since
+            # the dispatch refuses an explicit Pallas choice it cannot tile
+            impl = "ref"
         records.append({
             "site": f"{spec.name}:{ordinal}", "op": op_idx,
             "kernel": spec.name,
-            "impl": impls.get(op_idx, spec.default_impl),
+            "impl": impl,
             "sharded": sharded,
             "in_specs": in_specs, "out_specs": out_specs})
     return records

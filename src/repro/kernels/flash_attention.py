@@ -7,9 +7,9 @@ tiles HBM→VMEM exactly once while the q tile and the accumulator stay
 VMEM-resident.  Block sizes default to 128 — the MXU systolic array edge —
 so every matmul in the kernel is hardware-aligned.
 
-Validated on CPU with ``interpret=True`` against ``ref.reference_attention``
-(see tests/test_kernels.py); on TPU the same ``pl.pallas_call`` lowers to
-Mosaic.
+Validated on CPU in interpret mode against ``ref.reference_attention``
+(tests/test_kernels.py); on TPU the same ``pl.pallas_call`` lowers to
+Mosaic (tests/test_tpu_compile.py compiles it for a described v5e).
 """
 
 from __future__ import annotations
@@ -71,13 +71,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                        jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
-def flash_attention(q, k, v, *, causal: bool = True,
+def flash_attention(q, k, v, *, interpret: bool, causal: bool = True,
                     sm_scale: float | None = None,
                     block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K,
-                    interpret: bool = True):
+                    block_k: int = DEFAULT_BLOCK_K):
     """q: (B, H, S, hd); k, v: (B, H, T, hd) — same head count (the ops
-    wrapper expands GQA groups).  Returns (B, H, S, hd)."""
+    wrapper expands GQA groups).  Returns (B, H, S, hd).
+
+    ``interpret`` is required: ``kernels.ops.default_interpret`` resolves
+    it from the backend (Mosaic on TPU, the interpreter elsewhere).
+    """
     B, H, S, hd = q.shape
     T = k.shape[2]
     block_q = min(block_q, S)

@@ -5,8 +5,9 @@ Model code (``use_pallas=True`` paths) calls :func:`attention` /
 
 - resolves the implementation (``pallas`` vs ``ref``) from the ambient
   kernel-dispatch state (``repro.models.sharding.kernel_dispatch``) —
-  per-site plan decisions, backend auto-detection, feasibility fallback
-  for shapes the Pallas grid cannot tile (``registry.MIN_BLOCK``);
+  per-site plan decisions, else backend auto-detection; an explicit
+  ``pallas`` choice for a shape the Pallas grid cannot tile
+  (``registry.MIN_BLOCK``) raises;
 - runs the computation inside a **named jit** whose name starts with
   ``toast_kernel__`` — the tracer (``core.ir``) records that boundary as
   a single fused IR op (``prim="kernel:flash_attention"`` etc.) instead
@@ -22,7 +23,6 @@ Model code (``use_pallas=True`` paths) calls :func:`attention` /
 
 from __future__ import annotations
 
-import warnings
 from functools import lru_cache, partial
 
 import jax
@@ -37,31 +37,10 @@ from repro.kernels.rg_lru import rg_lru_scan
 __all__ = ["attention", "default_interpret", "gqa_flash_attention",
            "rg_lru"]
 
-_WARNED: set = set()
-
-
-def _warn_once(key: str, msg: str) -> None:
-    if key not in _WARNED:
-        _WARNED.add(key)
-        warnings.warn(msg, stacklevel=3)
-
-
-def _pick_block(n: int, target: int) -> int:
-    """Largest divisor of ``n`` at most ``target`` (see registry.pick_block).
-
-    Degenerate results (below ``MIN_BLOCK`` — primes, tiny remainders)
-    are the callers' cue to fall back to the reference impl rather than
-    launch a pathological block-1 Pallas grid.
-    """
-    return registry.pick_block(n, target)
-
 
 def default_interpret() -> bool:
-    """Auto-detected Pallas interpret flag: compiled on TPU/GPU only."""
-    try:
-        return jax.default_backend() not in ("tpu", "gpu")
-    except Exception:  # noqa: BLE001 — no backend at all
-        return True
+    """Pallas interpret flag for the running backend: Mosaic on TPU only."""
+    return jax.default_backend() != "tpu"
 
 
 def _dispatch():
@@ -75,34 +54,26 @@ def _resolve(kernel: str, dims: dict):
 
     Order of precedence: per-site plan decision from the dispatch state,
     then the state's default impl, then backend auto-detection (Pallas
-    on TPU/GPU, reference elsewhere).  An infeasible Pallas choice —
-    block tiling below ``MIN_BLOCK`` on the (local) shapes — falls back
-    to ``ref`` with a one-time warning, mirroring how the cost model
-    prices such sites.
+    on TPU where the shape tiles, reference elsewhere).  An explicit
+    Pallas choice whose shape cannot tile — a divisor block below
+    ``MIN_BLOCK`` — raises rather than run something else.
     """
     disp = _dispatch()
-    impl = None
-    interpret = None
-    site = None
+    impl = interpret = site = None
     if disp is not None:
         site = disp.next_site(kernel)
         impl = disp.impl_for(site)
         interpret = disp.interpret
-    if impl is None:
-        spec = registry.KERNELS[kernel]
-        on_accel = not default_interpret()
-        impl = "pallas" if (on_accel and "pallas" in spec.impls) \
-            else spec.default_impl
-        if not on_accel and "ref" in spec.impls:
-            impl = "ref"
+    on_tpu = not default_interpret()
     if interpret is None:
-        interpret = default_interpret()
-    if impl == "pallas" and not registry.pallas_feasible(kernel, dims):
-        _warn_once(
-            f"{kernel}:block:{tuple(sorted(dims.items()))}",
-            f"{kernel}: shape {dims} has no divisor block >= "
-            f"{MIN_BLOCK}; falling back to the reference impl")
-        impl = "ref"
+        interpret = not on_tpu
+    feasible = registry.pallas_feasible(kernel, dims)
+    if impl is None:
+        impl = "pallas" if feasible and on_tpu else "ref"
+    elif impl == "pallas" and not feasible:
+        raise ValueError(
+            f"{kernel}: pallas was chosen for shape {dims}, which has no "
+            f"divisor block >= {MIN_BLOCK}")
     return impl, interpret, site
 
 
@@ -115,12 +86,8 @@ def _maybe_shard_map(kernel: str, site, fn):
     if spec is None:
         return fn
     mesh, in_specs, out_specs = spec
-    try:
-        from jax.experimental.shard_map import shard_map
-    except Exception:  # noqa: BLE001 — older jax layouts
-        return fn
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -149,7 +116,8 @@ def _fa_fwd_jit(causal: bool):
             vt = v.transpose(0, 2, 1, 3)
             out = flash_attention(
                 qt, kt, vt, causal=causal,
-                block_q=_pick_block(S, 128), block_k=_pick_block(T, 128),
+                block_q=registry.pick_block(S, 128),
+                block_k=registry.pick_block(T, 128),
                 interpret=interpret)
             return out.transpose(0, 2, 1, 3)
         return _ref_attention_model_layout(q, k, v, causal)
@@ -215,27 +183,23 @@ def _legacy_gqa(q, k, v, causal, interpret):
     qt = q.transpose(0, 2, 1, 3)
     kt = jnp.repeat(k.transpose(0, 2, 1, 3), g, axis=1)
     vt = jnp.repeat(v.transpose(0, 2, 1, 3), g, axis=1)
-    bq, bk = _pick_block(S, 128), _pick_block(T, 128)
+    bq, bk = registry.pick_block(S, 128), registry.pick_block(T, 128)
     out = flash_attention(qt, kt, vt, causal=causal, block_q=bq,
                           block_k=bk, interpret=interpret)
     return out.transpose(0, 2, 1, 3)
 
 
-def gqa_flash_attention(q, k, v, *, causal: bool = True,
-                        interpret: bool | None = None):
+def gqa_flash_attention(q, k, v, *, causal: bool = True):
     """Model-layout GQA attention: q (B,S,H,hd); k, v (B,T,KV,hd).
 
     Groups are expanded to full heads, then the dispatch decides Pallas
-    vs reference per the ambient state; ``interpret=None`` auto-detects
-    (compiled on TPU/GPU, interpreter elsewhere).
+    vs reference (and interpret mode) per the ambient state.
     """
     B, S, H, hd = q.shape
     T = k.shape[1]
     dims = {"batch": B, "q_seq": S, "kv_seq": T, "heads": H,
             "head_dim": hd}
-    impl, auto_interp, _ = _resolve("flash_attention", dims)
-    if interpret is None:
-        interpret = auto_interp
+    impl, interpret, _ = _resolve("flash_attention", dims)
     if impl == "pallas":
         return _legacy_gqa(q, k, v, causal, interpret)
     g = H // k.shape[2]
@@ -256,8 +220,8 @@ def _lru_fwd_jit():
     def fwd(a, b, impl, interpret):
         if impl == "pallas":
             B, S, R = a.shape
-            return rg_lru_scan(a, b, block_r=_pick_block(R, 128),
-                               block_s=_pick_block(S, 256),
+            return rg_lru_scan(a, b, block_r=registry.pick_block(R, 128),
+                               block_s=registry.pick_block(S, 256),
                                interpret=interpret)
         return ref.reference_rg_lru(a, b)
 
@@ -296,11 +260,9 @@ def _lru_core(impl: str, interpret: bool):
     return lru
 
 
-def rg_lru(a, b, *, interpret: bool | None = None):
+def rg_lru(a, b):
     """Fused gated linear recurrence dispatch; a, b: (B, S, R)."""
     dims = registry.KERNELS["rg_lru"].dims_from_shapes((a.shape, b.shape))
-    impl, auto_interp, site = _resolve("rg_lru", dims)
-    if interpret is None:
-        interpret = auto_interp
+    impl, interpret, site = _resolve("rg_lru", dims)
     fn = _maybe_shard_map("rg_lru", site, _lru_core(impl, interpret))
     return fn(a, b)

@@ -32,8 +32,8 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, SHAPES, cells, get_config
 from repro.core.cost_model import HardwareSpec
-from repro.launch.mesh import (compat_cost_analysis, make_production_mesh,
-                               mesh_context, production_mesh_spec)
+from repro.launch.mesh import (make_mesh, make_production_mesh,
+                               production_mesh_spec)
 from repro.launch.specs import specs_from_rules, step_and_inputs
 from repro.models.sharding import (MANUAL_RULES, MANUAL_RULES_MULTIPOD,
                                    logical_rules)
@@ -63,8 +63,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     shape = ShapeConfig("mini", 64, 8, "train") if smoke \
         else SHAPES[shape_name]
     if smoke:
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -110,7 +109,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
             is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
 
     t0 = time.perf_counter()
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         with logical_rules(rules):
             lowered = jax.jit(fn, in_shardings=in_shardings).lower(*args)
             t_lower = time.perf_counter() - t0
@@ -118,7 +117,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     t_compile = time.perf_counter() - t0 - t_lower
 
     mem = compiled.memory_analysis()
-    ca = compat_cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     hlo = compiled.as_text()
     if os.environ.get("REPRO_KEEP_HLO"):
         import gzip

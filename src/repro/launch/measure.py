@@ -20,7 +20,9 @@ and persists the calibrated spec through the plan store
 
 Simulated-mesh caveat: all "devices" share the host's cores, so absolute
 times are not accelerator times — rank correlation and calibrated-model
-error are the meaningful outputs (see ``docs/measure.md``).
+error are the meaningful outputs (see ``docs/measure.md``).  For the same
+reason the parent half refuses to run in a process whose backend is a
+TPU.
 
 Usage::
 
@@ -77,7 +79,7 @@ def run_worker_job(job: dict) -> dict:
     from repro.configs import get_config
     from repro.configs.base import ShapeConfig
     from repro.core.partitioner import ShardingPlan
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.launch.specs import step_and_inputs
 
     plan = ShardingPlan.from_dict(job["plan"])
@@ -100,7 +102,7 @@ def run_worker_job(job: dict) -> dict:
     shape = ShapeConfig(s.get("name", "measure"), s["seq_len"],
                         s["global_batch"], s["kind"])
     fn, args, _ = step_and_inputs(cfg, shape)
-    mesh = compat_make_mesh(tuple(plan.mesh.sizes), tuple(plan.mesh.axes))
+    mesh = make_mesh(plan.mesh.sizes, plan.mesh.axes)
     applied = plan.apply(fn, mesh)
 
     t0 = time.perf_counter()
@@ -110,9 +112,8 @@ def run_worker_job(job: dict) -> dict:
         # plan's internal assignment — without them GSPMD propagates the
         # body from the in/out shardings alone and can diverge from the
         # plan (and from the predicted collective multiset)
-        from repro.launch.mesh import mesh_context
         from repro.models.sharding import logical_rules
-        with mesh_context(mesh), \
+        with jax.set_mesh(mesh), \
                 logical_rules(plan.logical_rules or None):
             lowered = applied.lower(*args)
         compiled = lowered.compile()
@@ -185,6 +186,21 @@ def _worker_main() -> None:
 
 # -- parent half -------------------------------------------------------------
 
+def _refuse_on_chip() -> None:
+    """Refuse to start a CPU worker from a process that holds a TPU.
+
+    The worker is the simulated-mesh CPU rehearsal.  Started from a
+    process on the chip it would report CPU timings as if they were the
+    chip's, and a child cannot share the chip its parent holds.
+    """
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "repro.launch.measure executes plans on simulated CPU devices "
+            "in a child process; this process holds a TPU, so its numbers "
+            "would be CPU numbers.  Measure in-process on the chip instead.")
+
+
 def _worker_env(num_devices: int) -> dict:
     env = dict(os.environ)
     flags = _FORCE_FLAG.sub("", env.get("XLA_FLAGS", "")).strip()
@@ -224,7 +240,11 @@ def measure_plan(arch: str, shape, plan, *, reduced: bool = True,
     Returns:
         The worker's result dict ("status", "measured_s", "runs_s",
         "compile_s", "peak_bytes", "devices", "error").
+
+    Raises:
+        RuntimeError: when the calling process's backend is a TPU.
     """
+    _refuse_on_chip()
     if not isinstance(shape, dict):
         shape = {"name": shape.name, "seq_len": shape.seq_len,
                  "global_batch": shape.global_batch, "kind": shape.kind}
@@ -258,7 +278,11 @@ def hlo_for_plan(arch: str, shape, plan, *, reduced: bool = True,
         The worker result: "status", "coll_bytes" (``{kind: bytes}``,
         loop-aware), "unknown_dtypes", "top_collectives",
         "while_trips", "compile_s", "peak_bytes", "error".
+
+    Raises:
+        RuntimeError: when the calling process's backend is a TPU.
     """
+    _refuse_on_chip()
     if not isinstance(shape, dict):
         shape = {"name": shape.name, "seq_len": shape.seq_len,
                  "global_batch": shape.global_batch, "kind": shape.kind}

@@ -13,36 +13,23 @@ import jax
 from repro.core.cost_model import MeshSpec
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer releases."""
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``.
 
-
-def mesh_context(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh:
-    ``jax.set_mesh`` on new jax, the ``Mesh`` context manager on old."""
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
-
-
-def compat_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized to a dict (older jax returns
-    a singleton list of dicts)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    The installed JAX defaults new meshes to ``Explicit`` axes; plans
+    are applied through GSPMD propagation (``jit`` in/out shardings plus
+    the models' ``constrain`` hooks), which needs ``Auto`` axes.
+    """
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def production_mesh_spec(*, multi_pod: bool = False) -> MeshSpec:

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.models.sharding import MANUAL_RULES, logical_rules
 from repro.train.steps import make_decode_step
@@ -46,7 +47,7 @@ def toast_decode_rules(cfg, batch: int, max_seq: int):
     from repro.api import Replicate, Request, Session
     from repro.configs.base import ShapeConfig
     from repro.core.cost_model import MeshSpec
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.launch.specs import step_and_inputs
     n_dev = len(jax.devices())
     if n_dev < 2:
@@ -64,7 +65,7 @@ def toast_decode_rules(cfg, batch: int, max_seq: int):
         if has_kv else ()))
     print(f"[toast] cost={plan.cost:.4f} rules={plan.logical_rules} "
           f"search={plan.search_seconds:.1f}s")
-    return dict(plan.logical_rules), compat_make_mesh(sizes, mesh_spec.axes)
+    return dict(plan.logical_rules), make_mesh(sizes, mesh_spec.axes)
 
 
 def main() -> None:
@@ -79,6 +80,7 @@ def main() -> None:
     ap.add_argument("--plan", choices=["manual", "toast"],
                     default="manual")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -100,10 +102,9 @@ def main() -> None:
     rules, mesh = (toast_decode_rules(cfg, B, max_seq)
                    if args.plan == "toast" else ({}, None))
     from contextlib import nullcontext
-    from repro.launch.mesh import mesh_context
     # the with_sharding_constraint hooks need an ambient mesh, else the
     # searched rules silently no-op
-    with mesh_context(mesh) if mesh is not None else nullcontext(), \
+    with jax.set_mesh(mesh) if mesh is not None else nullcontext(), \
             logical_rules(rules or None):
         # prefill via the decode path (token-by-token here; the production
         # prefill lowers the full-sequence forward — see launch/dryrun.py)

@@ -32,6 +32,7 @@ from repro.core.cost_model import MeshSpec
 from repro.core.mcts import MCTSConfig
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.data.pipeline import DataConfig, Pipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.specs import (specs_from_rules, state_logical_axes,
                                 step_and_inputs)
 from repro.models.sharding import MANUAL_RULES, logical_rules
@@ -40,7 +41,7 @@ from repro.optim import compression as gc_mod
 
 
 def build_mesh(spec: MeshSpec):
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
     n = len(jax.devices())
     sizes = []
     remaining = n
@@ -48,7 +49,7 @@ def build_mesh(spec: MeshSpec):
         s = min(s, remaining)
         sizes.append(s)
         remaining //= s
-    return compat_make_mesh(tuple(sizes), spec.axes)
+    return make_mesh(sizes, spec.axes)
 
 
 def toast_rules(cfg, shape, mesh_spec: MeshSpec, budget_rounds=6,
@@ -103,8 +104,7 @@ def run_once(args, attempt: int) -> bool:
     jit_step = jax.jit(train_step, donate_argnums=0)
     t0 = time.perf_counter()
     try:
-        from repro.launch.mesh import mesh_context
-        with mesh_context(mesh), logical_rules(rules):
+        with jax.set_mesh(mesh), logical_rules(rules):
             for i in range(start_step, args.steps):
                 _, batch = next(pipe)
                 if args.fail_at is not None and i == args.fail_at and \
@@ -142,6 +142,7 @@ def main() -> None:
                     help="inject a failure at this step (first attempt)")
     ap.add_argument("--max-failures", type=int, default=2)
     args = ap.parse_args()
+    enable_compile_cache()
 
     for attempt in range(args.max_failures + 1):
         try:

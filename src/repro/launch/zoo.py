@@ -47,6 +47,7 @@ from repro.configs.base import ShapeConfig
 from repro.core.cost_model import HardwareSpec, MeshSpec, ShardingState
 from repro.core.portfolio import PortfolioConfig, PortfolioMember
 from repro.core.search import BeamConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.specs import step_and_inputs
 
 # axis names by mesh rank, matching the repo's conventions elsewhere
@@ -993,6 +994,7 @@ def main(argv: list[str] | None = None) -> dict:
         mesh = parse_mesh(args.mesh)
     except ValueError as e:
         ap.error(str(e))                        # usage + exit 2
+    enable_compile_cache()
     store = None if args.no_plan_store else PlanStore(args.plan_store)
     hw = HardwareSpec()
     if args.use_calibrated_hw:

@@ -68,8 +68,7 @@ class TestHloAnalyzer:
         assert s.flops == pytest.approx(12 * 2 * 32 * 64 * 64, rel=0.02)
         assert 12 in s.while_trips.values()
         # XLA's own analysis undercounts by the trip count
-        from repro.launch.mesh import compat_cost_analysis
-        assert compat_cost_analysis(c)["flops"] < s.flops / 6
+        assert c.cost_analysis()["flops"] < s.flops / 6
 
     def test_nested_grad_scan(self):
         def loop(x, ws):
@@ -93,19 +92,19 @@ from repro.configs import get_config, SHAPES
 from repro.configs.base import ShapeConfig
 from repro.launch.specs import step_and_inputs, specs_from_rules
 from repro.launch.hlo_analysis import summarize
-from repro.launch.mesh import compat_make_mesh, mesh_context
+from repro.launch.mesh import make_mesh
 from repro.models.sharding import MANUAL_RULES, logical_rules
 
 cfg = get_config("qwen2_05b").reduced()
 shape = ShapeConfig("mini", 64, 8, "train")
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
 fn, args, names = step_and_inputs(cfg, shape)
 spec_tree = specs_from_rules(args, names, dict(MANUAL_RULES), axis_sizes)
 in_sh = jax.tree_util.tree_map(
     lambda s: jax.sharding.NamedSharding(mesh, s), spec_tree,
     is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
-with mesh_context(mesh), logical_rules(dict(MANUAL_RULES)):
+with jax.set_mesh(mesh), logical_rules(dict(MANUAL_RULES)):
     compiled = jax.jit(fn, in_shardings=in_sh).lower(*args).compile()
 mem = compiled.memory_analysis()
 s = summarize(compiled.as_text())
@@ -169,3 +168,65 @@ class TestParseMesh:
         err = capsys.readouterr().err
         assert "bad mesh spec" in err
         assert "usage:" in err
+
+
+# --- persistent compilation cache placement ---------------------------------
+
+
+class TestCompileCache:
+    @pytest.fixture(autouse=True)
+    def _restore_config(self):
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_env_dir_is_left_to_jax(self, monkeypatch, tmp_path):
+        from repro.launch.compile_cache import enable_compile_cache
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+    def test_fixed_dir_in_checkout_when_unset(self, monkeypatch):
+        import pathlib
+        from repro.launch.compile_cache import enable_compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        got = enable_compile_cache()
+        assert got == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+        ignored = (root / ".gitignore").read_text().split()
+        assert ".jax_cache/" in ignored
+
+
+# --- the CPU measurement worker is never started from the chip -------------
+
+
+@pytest.mark.parametrize("entry", ["measure_plan", "hlo_for_plan"])
+def test_measure_refuses_when_process_holds_a_tpu(entry, monkeypatch):
+    from repro.launch import measure
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(measure, "_run_worker_subprocess",
+                        lambda *a, **k: pytest.fail("spawned a CPU worker"))
+    with pytest.raises(RuntimeError, match="holds a TPU"):
+        getattr(measure, entry)("qwen2_05b", {}, None)
+
+
+# --- chip_smoke.py never reports a result off the chip ----------------------
+
+
+@pytest.mark.parametrize("alone", [False, True])
+def test_chip_smoke_fails_without_tpu(alone, tmp_path):
+    """On the CPU, and from a directory holding nothing else of the
+    repo, the smoke exits non-zero and prints no JSON verdict."""
+    import os
+    import pathlib
+    import shutil
+    script = pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    if alone:
+        script = pathlib.Path(shutil.copy(script, tmp_path))
+    res = subprocess.run([sys.executable, str(script)], capture_output=True,
+                         text=True, timeout=300, cwd=tmp_path,
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert res.returncode != 0
+    assert '"ok"' not in res.stdout

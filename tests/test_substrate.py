@@ -96,8 +96,8 @@ class TestCheckpoint:
     def test_elastic_restore_onto_sharding(self, tmp_path):
         """Restore re-places leaves with explicit shardings (any mesh)."""
         save(tmp_path, 1, self._tree(2))
-        from repro.launch.mesh import compat_make_mesh
-        mesh = compat_make_mesh((1,), ("x",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1,), ("x",))
         sh = jax.sharding.NamedSharding(mesh,
                                         jax.sharding.PartitionSpec())
         shardings = jax.tree_util.tree_map(lambda _: sh, self._tree())

@@ -1,0 +1,140 @@
+"""Compiles for a described TPU v5e: the chip's compiler, no chip.
+
+Interpret mode accepts kernels that Mosaic refuses (a dynamic slice of a
+loaded value, a slice not aligned to the tiling, too much VMEM).  These
+tests compile the main path's kernels at real widths, and the fused
+dispatch through ``shard_map`` on a 2x2 mesh, with the TPU compiler for a
+``v5e:2x2`` topology described in a fixture.  Nothing runs: the
+assertions are about what the compiled HLO holds.
+
+The topology is described inside a module-scoped fixture, never at
+import, so that every pytest-xdist worker collects the same tests and
+only the worker given this file loads the TPU library.  The persistent
+compilation cache is off around these compiles: a compile for a
+described chip cannot be read back without one.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops, registry
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.rg_lru import rg_lru_scan
+from repro.models.sharding import KernelDispatch, kernel_dispatch
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:                      # noqa: BLE001
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh22(topo):
+    return Mesh(np.array(topo.devices[:4]).reshape(2, 2), ("data", "model"))
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _holds_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_flash_attention_compiles_at_qwen2_05b_width(one_chip, dtype):
+    """qwen2_05b attention: 14 heads of 64, 1024 tokens."""
+    q = _sds((1, 14, 1024, 64), dtype, one_chip)
+    compiled = jax.jit(
+        lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                        interpret=False)
+    ).lower(q, q, q).compile()
+    assert _holds_kernel(compiled)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_rg_lru_compiles_at_recurrentgemma_width(one_chip, dtype):
+    """recurrentgemma_2b's RG-LRU as ``layers.rglru_apply`` calls it:
+    R = 3840 channels (f32 there), blocks as ``kernels.ops`` picks them."""
+    B, S, R = 1, 2048, 3840
+    a = _sds((B, S, R), dtype, one_chip)
+    compiled = jax.jit(
+        lambda a, b: rg_lru_scan(a, b, block_r=registry.pick_block(R, 128),
+                                 block_s=registry.pick_block(S, 256),
+                                 interpret=False)
+    ).lower(a, a).compile()
+    assert _holds_kernel(compiled)
+
+
+def test_sharded_attention_site_lowers_through_shard_map(mesh22):
+    """A plan-sharded site (batch on data, heads on model) lowers to a
+    per-device Mosaic kernel, forward and backward."""
+    spec = P("data", None, "model", None)
+    disp = KernelDispatch(impls={"flash_attention:0": "pallas"},
+                          interpret=False, mesh=mesh22,
+                          specs={"flash_attention:0": ((spec,) * 3, spec)})
+    x = _sds((4, 256, 14, 64), jnp.bfloat16, NamedSharding(mesh22, spec))
+
+    def loss(q, k, v):
+        o = ops.attention(q, k, v, causal=True)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    with kernel_dispatch(disp):
+        lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))
+                          ).lower(x, x, x)
+    compiled = lowered.compile()
+    assert _holds_kernel(compiled)
+    # per-device kernel: each of the 4 devices sees 2 of 4 rows and 7 of
+    # 14 heads
+    assert "bf16[2,7,256,64]" in compiled.as_text()
+
+
+def test_fused_train_step_plan_compiles_on_2x2(mesh22, monkeypatch):
+    """Session -> partition -> plan.apply of a fused (``use_pallas``)
+    train step, compiled for four described chips."""
+    from repro.api import Request, Session
+    from repro.configs import get_config
+    from repro.configs.base import ShapeConfig
+    from repro.core.cost_model import MeshSpec
+    from repro.launch.specs import step_and_inputs
+
+    # the dispatch asks the running backend (here the CPU) whether to
+    # interpret; this compile targets the TPU
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    cfg = dataclasses.replace(get_config("qwen2_05b").reduced(),
+                              use_pallas=True)
+    fn, args, names = step_and_inputs(
+        cfg, ShapeConfig("tpu_compile", 256, 4, "train"))
+    plan = Session(fn, args).partition(Request(
+        mesh=MeshSpec(("data", "model"), (2, 2)), backend="greedy",
+        min_dims=1, logical_axes=names))
+    assert plan.kernel_sites
+    assert all(r["impl"] == "pallas" for r in plan.kernel_sites)
+    with jax.set_mesh(mesh22):
+        compiled = plan.apply(fn, mesh22).lower(*args).compile()
+    assert _holds_kernel(compiled)
