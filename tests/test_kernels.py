@@ -91,7 +91,7 @@ class TestFlashAttention:
                                    np.asarray(want, np.float32),
                                    rtol=tol, atol=tol)
 
-    def test_prime_seq_falls_back_to_ref_with_one_warning(self):
+    def test_prime_seq_explicit_pallas_raises(self):
         """A prime seq len cannot tile: an explicit Pallas choice raises,
         while the automatic default runs the reference and stays correct."""
         from repro.models.sharding import KernelDispatch, kernel_dispatch
@@ -155,7 +155,7 @@ class TestRGLRU:
                                    np.asarray(want, np.float32),
                                    rtol=tol, atol=tol)
 
-    def test_prime_channels_fall_back_to_ref(self):
+    def test_prime_channels_explicit_pallas_raises(self):
         """A prime channel count cannot tile: an explicit Pallas choice
         raises, the automatic default runs the reference correctly."""
         from repro.models.sharding import KernelDispatch, kernel_dispatch
