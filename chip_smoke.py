@@ -119,8 +119,8 @@ def search(cfg, shape: ShapeConfig, mesh_shape: tuple[int, int]):
     sess = Session(fn, args)
     phases = {k: round(v, 3)
               for k, v in sess.artifacts.phase_seconds.items()}
-    log(f"[analysis] {sess.analysis_seconds:.3f}s phases={phases} "
-        f"ops={len(sess.artifacts.prog.ops)}")
+    log(f"[analysis] {sum(sess.artifacts.phase_seconds.values()):.3f}s "
+        f"phases={phases} ops={len(sess.artifacts.prog.ops)}")
     plan = sess.partition(Request(mesh=MeshSpec(AXES, mesh_shape),
                                   logical_axes=names))
     log(f"[plan] mesh={mesh_shape} backend={plan.backend} "

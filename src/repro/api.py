@@ -59,6 +59,7 @@ from repro.core.search import SearchBackend, get_backend
 from repro.core.verify import (Finding, VerifyReport,  # noqa: F401
                                attach_conformance, conformance_check,
                                verify_state)
+from repro.spans import span
 
 __all__ = [
     "Constraint", "ConstraintError", "CoSearchResult", "Finding",
@@ -252,9 +253,7 @@ class Session:
         self.fn = fn
         self.args = args
         self.kwargs = kwargs
-        t0 = time.perf_counter()
         self.artifacts = artifacts or analyze(fn, args, kwargs)
-        self.analysis_seconds = time.perf_counter() - t0
         self.plan_store = plan_store
         self._fingerprint: str | None = None
         self._cost_models: dict[tuple[MeshSpec, HardwareSpec],
@@ -361,8 +360,11 @@ class Session:
                     hit.check(request.constraints)
                 return hit
 
-        cm = self._cost_model(request.mesh, request.hw)
-        actions = self._actions(request.mesh, request.min_dims)
+        phases: dict = {}
+        with span("cost_model", phases):
+            cm = self._cost_model(request.mesh, request.hw)
+        with span("actions", phases):
+            actions = self._actions(request.mesh, request.min_dims)
         root = ShardingState()
         if cs is not None:
             actions = cs.prune(actions)
@@ -371,8 +373,9 @@ class Session:
         evaluator = IncrementalEvaluator(cm, constraints=cs)
         search_config = _with_guidance(engine, request.search_config,
                                        request.guidance)
-        result = engine.search(evaluator, actions, search_config,
-                               root=root)
+        with span("search", phases):
+            result = engine.search(evaluator, actions, search_config,
+                                   root=root)
         elapsed = time.perf_counter() - t0
 
         eval_stats = evaluator.stats.as_dict()
@@ -382,12 +385,15 @@ class Session:
                 "early_stopped": result.early_stopped,
                 "members": [m.as_dict() for m in result.members],
             }
-        plan = self._build_plan(
-            request, result.best_state, cm,
-            cost=result.best_cost,
-            breakdown=evaluator.evaluate(result.best_state).as_dict(),
-            backend=engine.name, search_seconds=elapsed,
-            evaluations=result.evaluations, eval_stats=eval_stats)
+        # the plan keeps this dict, so "build_plan" lands in it as well
+        eval_stats["phase_seconds"] = phases
+        with span("build_plan", phases):
+            plan = self._build_plan(
+                request, result.best_state, cm,
+                cost=result.best_cost,
+                breakdown=evaluator.evaluate(result.best_state).as_dict(),
+                backend=engine.name, search_seconds=elapsed,
+                evaluations=result.evaluations, eval_stats=eval_stats)
         if request.constraints:
             plan.check(request.constraints)
         if store is not None:

@@ -873,16 +873,15 @@ class CostModel:
     def evaluate(self, state: ShardingState) -> CostBreakdown:
         bd = self._cache.get(state)
         if bd is None:
-            bd, _, _, _ = self.evaluate_with_diff(state)
+            bd, _, _ = self.evaluate_with_diff(state)
             self._cache[state] = bd
         return bd
 
     def evaluate_with_diff(self, state: ShardingState
-                           ) -> tuple[CostBreakdown, dict, dict, int]:
+                           ) -> tuple[CostBreakdown, dict, dict]:
         """Diff-from-base evaluation: re-cost only ops/values touched by the
-        state.  Returns (breakdown, {op: row != base}, {vid: bytes != base},
-        number of rows re-costed) — the record the incremental evaluator
-        chains from."""
+        state.  Returns (breakdown, {op: row != base}, {vid: bytes != base})
+        — the record the incremental evaluator chains from."""
         color_axes, _ = state.as_dicts()
         suppressed = self.suppressed_for(state.bits)
         dirty_ops, dirty_vals = self.state_dirty_sets(state)
@@ -906,7 +905,7 @@ class CostModel:
         peak = self.peak_with_overrides(vbytes)
         bd = CostBreakdown(totals[0], totals[1], totals[2], peak,
                            totals[3], totals[4])
-        return bd, rows, vbytes, len(dirty_ops)
+        return bd, rows, vbytes
 
     def evaluate_dense(self, state: ShardingState) -> CostBreakdown:
         """The original exhaustive abstract interpretation — every op

@@ -40,7 +40,6 @@ class EvalStats:
     cache_hits: int = 0          # answered from the transposition cache
     incremental_evals: int = 0   # parent-diff evaluations
     base_evals: int = 0          # from-base (no parent record) evaluations
-    rows_recosted: int = 0       # op cost rows recomputed, all evals
 
     def as_dict(self) -> dict:
         """Plain-dict view (JSON-serializable)."""
@@ -195,9 +194,8 @@ class IncrementalEvaluator:
         return rec
 
     def _record_from_base(self, state: ShardingState) -> _Record:
-        bd, rows, vbytes, n_recosted = self.cm.evaluate_with_diff(state)
+        bd, rows, vbytes = self.cm.evaluate_with_diff(state)
         self.stats.base_evals += 1
-        self.stats.rows_recosted += n_recosted
         return self._store(state, _Record(rows, vbytes, bd))
 
     def _record_from_parent(self, prec: _Record, parent: ShardingState,
@@ -235,7 +233,6 @@ class IncrementalEvaluator:
                     rows.pop(i, None)
                 else:
                     rows[i] = new
-        self.stats.rows_recosted += len(dirty_ops)
 
         vbytes = dict(prec.vbytes)
         bytes_changed = False

@@ -20,6 +20,7 @@ import numpy as np
 from jax.extend import core as jcore
 
 from repro.kernels import registry as kernel_registry
+from repro.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -381,8 +382,10 @@ def program_fingerprint(prog: Program) -> str:
 
 def extract_program(fn, *args, **kwargs) -> Program:
     """Trace ``fn`` to a jaxpr and extract the flat Program."""
-    closed = jax.make_jaxpr(fn)(*args, **kwargs)
-    return extract_from_jaxpr(closed, args, kwargs)
+    with span("trace.jaxpr"):
+        closed = jax.make_jaxpr(fn)(*args, **kwargs)
+    with span("trace.ir"):
+        return extract_from_jaxpr(closed, args, kwargs)
 
 
 def extract_from_jaxpr(closed, args=(), kwargs=None) -> Program:

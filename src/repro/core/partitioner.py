@@ -20,7 +20,6 @@ from __future__ import annotations
 import dataclasses
 import json
 import math
-import time
 from collections import Counter, defaultdict
 from typing import Callable
 
@@ -36,6 +35,7 @@ from repro.core.ir import Program, extract_program
 from repro.core.mcts import MCTSConfig
 from repro.core.nda import NDAResult, run_nda
 from repro.core.search import SearchBackend
+from repro.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,7 +103,9 @@ class ShardingPlan:
         num_resolution_bits: supergroup resolution bits (paper §3.6).
         backend: name of the search backend that produced the plan.
         eval_stats: evaluator work counters (cache hits / incremental /
-            from-base evaluations).
+            from-base evaluations) and, under ``"phase_seconds"``, the
+            wall seconds of the partition phases (``cost_model``,
+            ``actions``, ``search``, ``build_plan``).
         fingerprint: deterministic program fingerprint
             (:func:`repro.core.ir.program_fingerprint`) when known.
         cached: True when the plan was served from a
@@ -589,14 +591,13 @@ def analyze(fn: Callable, args: tuple, kwargs: dict | None = None
         :class:`ToastArtifacts` reusable across meshes and searches,
         with per-phase wall times in ``phase_seconds``.
     """
-    t0 = time.perf_counter()
-    prog = extract_program(fn, *args, **(kwargs or {}))
-    t1 = time.perf_counter()
-    nda = run_nda(prog)
-    t2 = time.perf_counter()
-    analysis = analyze_conflicts(nda)
-    t3 = time.perf_counter()
-    phases = {"trace": t1 - t0, "nda": t2 - t1, "conflicts": t3 - t2}
+    phases: dict = {}
+    with span("trace", phases):
+        prog = extract_program(fn, *args, **(kwargs or {}))
+    with span("nda", phases):
+        nda = run_nda(prog)
+    with span("conflicts", phases):
+        analysis = analyze_conflicts(nda)
     return ToastArtifacts(prog, nda, analysis, phase_seconds=phases)
 
 

@@ -6,7 +6,10 @@ the straggler/elasticity story for the data layer: no host ever blocks on
 a data service, and recovery after preemption is recompute-free.
 
 A background prefetch thread keeps ``prefetch`` batches ready so host-side
-data generation overlaps device compute.
+data generation overlaps device compute.  Its work shows in a profile as
+the span ``toast.data.make``; the consumer's wait for a batch as
+``toast.data.wait``, counted in ``Pipeline.waits_empty`` and
+``Pipeline.wait_s``.
 """
 
 from __future__ import annotations
@@ -14,11 +17,13 @@ from __future__ import annotations
 import dataclasses
 import queue
 import threading
+import time
 
 import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeConfig
+from repro.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,10 +63,19 @@ def _batch_for(cfg: ModelConfig, shape: ShapeConfig, dcfg: DataConfig,
 
 
 class Pipeline:
+    """Batches in step order from a prefetch thread.
+
+    Attributes:
+        waits_empty: calls of ``next`` that found no batch ready.
+        wait_s: wall seconds ``next`` spent getting batches.
+    """
+
     def __init__(self, cfg: ModelConfig, shape: ShapeConfig,
                  dcfg: DataConfig = DataConfig(), start_step: int = 0):
         self.cfg, self.shape, self.dcfg = cfg, shape, dcfg
         self._step = start_step
+        self.waits_empty = 0
+        self.wait_s = 0.0
         self._q: queue.Queue = queue.Queue(maxsize=max(dcfg.prefetch, 1))
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._worker, daemon=True)
@@ -70,7 +84,8 @@ class Pipeline:
     def _worker(self) -> None:
         step = self._step
         while not self._stop.is_set():
-            batch = _batch_for(self.cfg, self.shape, self.dcfg, step)
+            with span("data.make"):
+                batch = _batch_for(self.cfg, self.shape, self.dcfg, step)
             while not self._stop.is_set():
                 try:
                     self._q.put((step, batch), timeout=0.1)
@@ -80,7 +95,14 @@ class Pipeline:
             step += 1
 
     def __next__(self):
-        step, batch = self._q.get()
+        t0 = time.perf_counter()
+        with span("data.wait"):
+            try:
+                step, batch = self._q.get_nowait()
+            except queue.Empty:
+                self.waits_empty += 1
+                step, batch = self._q.get()
+        self.wait_s += time.perf_counter() - t0
         return step, batch
 
     def __iter__(self):

@@ -113,4 +113,7 @@ def flash_attention(q, k, v, *, interpret: bool, causal: bool = True,
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
+        # the name of the jit around it in kernels.ops, so that a trace
+        # finds the kernel by that prefix whichever name the event takes
+        name=f"toast_kernel__flash_attention__causal_{int(causal)}",
     )(q, k, v)

@@ -131,10 +131,11 @@ def _fa_bwd_jit(causal: bool):
     """Named backward jit — traces as ``kernel:flash_attention_bwd``."""
 
     def bwd(q, k, v, g):
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: _ref_attention_model_layout(
-                q_, k_, v_, causal), q, k, v)
-        return vjp(g)
+        with jax.named_scope("attn_bwd"):
+            _, vjp = jax.vjp(
+                lambda q_, k_, v_: _ref_attention_model_layout(
+                    q_, k_, v_, causal), q, k, v)
+            return vjp(g)
 
     bwd.__name__ = \
         f"toast_kernel__flash_attention_bwd__causal={int(causal)}"

@@ -102,23 +102,26 @@ def run_once(args, attempt: int) -> bool:
     pipe = Pipeline(cfg, shape, DataConfig(seed=args.seed),
                     start_step=start_step)
     jit_step = jax.jit(train_step, donate_argnums=0)
-    t0 = time.perf_counter()
+    t0, wait0 = time.perf_counter(), 0.0
     try:
         with jax.set_mesh(mesh), logical_rules(rules):
             for i in range(start_step, args.steps):
-                _, batch = next(pipe)
-                if args.fail_at is not None and i == args.fail_at and \
-                        attempt == 0:
-                    raise RuntimeError("injected node failure")
-                state, metrics = jit_step(state, batch)
+                with jax.profiler.StepTraceAnnotation("train", step_num=i):
+                    _, batch = next(pipe)
+                    if args.fail_at is not None and i == args.fail_at \
+                            and attempt == 0:
+                        raise RuntimeError("injected node failure")
+                    state, metrics = jit_step(state, batch)
                 if (i + 1) % args.ckpt_every == 0 or i + 1 == args.steps:
                     ckpt.save_async(i + 1, state)
                 if (i + 1) % args.log_every == 0:
                     dt = (time.perf_counter() - t0) / args.log_every
-                    t0 = time.perf_counter()
+                    wait = (pipe.wait_s - wait0) / args.log_every
+                    t0, wait0 = time.perf_counter(), pipe.wait_s
                     print(f"step {i+1}: loss={float(metrics['loss']):.4f} "
                           f"gnorm={float(metrics['grad_norm']):.3f} "
-                          f"{dt*1e3:.0f}ms/step", flush=True)
+                          f"{dt*1e3:.0f}ms/step "
+                          f"(data wait {wait*1e3:.1f}ms/step)", flush=True)
         ckpt.wait()
         return True
     finally:

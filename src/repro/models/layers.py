@@ -225,14 +225,15 @@ def init_mlp(cfg, key):
 
 
 def mlp_apply(cfg, p, x):
-    h = rmsnorm(x, p["ln"])
-    u = h @ p["wi"]
-    u = constrain(u, ("act_batch", "seq", "hidden"))
-    if cfg.mlp == "swiglu":
-        u = jax.nn.silu(h @ p["wg"]) * u
-    else:
-        u = jax.nn.gelu(u)
-    return x + (u @ p["wo"])
+    with jax.named_scope("mlp"):
+        h = rmsnorm(x, p["ln"])
+        u = h @ p["wi"]
+        u = constrain(u, ("act_batch", "seq", "hidden"))
+        if cfg.mlp == "swiglu":
+            u = jax.nn.silu(h @ p["wg"]) * u
+        else:
+            u = jax.nn.gelu(u)
+        return x + (u @ p["wo"])
 
 
 def init_moe(cfg, key):

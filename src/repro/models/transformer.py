@@ -256,15 +256,16 @@ def forward(cfg, params, tokens, *, patch_embeds=None, frames=None):
     S = h.shape[1]
     positions = jnp.arange(S)[None, :]
     h = _run_layers(cfg, params, h, positions, enc_out=enc_out)
-    h = L.rmsnorm(h, params["final_ln"])
-    logits = h @ params["unembed"]
-    if cfg.logits_vocab_shard:
-        # an axis shards one dim per tensor: prefer vocab over seq here —
-        # CE then reduces over the sharded vocab locally (small all-reduce)
-        # instead of materialising seq-sharded fp32 logits + a vocab
-        # all-gather in the backward pass.
-        return constrain(logits, ("act_batch", None, "vocab"))
-    return constrain(logits, ("act_batch", "seq", "vocab"))
+    with jax.named_scope("head_loss"):
+        h = L.rmsnorm(h, params["final_ln"])
+        logits = h @ params["unembed"]
+        if cfg.logits_vocab_shard:
+            # an axis shards one dim per tensor: prefer vocab over seq
+            # here — CE then reduces over the sharded vocab locally (small
+            # all-reduce) instead of materialising seq-sharded fp32 logits
+            # + a vocab all-gather in the backward pass.
+            return constrain(logits, ("act_batch", None, "vocab"))
+        return constrain(logits, ("act_batch", "seq", "vocab"))
 
 
 # ---------------------------------------------------------------------------

@@ -62,32 +62,33 @@ def clip_by_global_norm(grads, max_norm):
 
 
 def apply_updates(cfg: AdamConfig, state: AdamState, params, grads):
-    grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
-    step = state.step + 1
-    lr = schedule(cfg, step)
-    b1, b2 = cfg.b1, cfg.b2
-    bc1 = 1 - b1 ** step.astype(jnp.float32)
-    bc2 = 1 - b2 ** step.astype(jnp.float32)
-    dt = jnp.dtype(cfg.state_dtype)
+    with jax.named_scope("optimizer"):
+        grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip)
+        step = state.step + 1
+        lr = schedule(cfg, step)
+        b1, b2 = cfg.b1, cfg.b2
+        bc1 = 1 - b1 ** step.astype(jnp.float32)
+        bc2 = 1 - b2 ** step.astype(jnp.float32)
+        dt = jnp.dtype(cfg.state_dtype)
 
-    def upd(p, g, m, v):
-        g32 = g.astype(jnp.float32)
-        m32 = b1 * m.astype(jnp.float32) + (1 - b1) * g32
-        v32 = b2 * v.astype(jnp.float32) + (1 - b2) * g32 * g32
-        mh = m32 / bc1
-        vh = v32 / bc2
-        delta = lr * (mh / (jnp.sqrt(vh) + cfg.eps) +
-                      cfg.weight_decay * p.astype(jnp.float32))
-        return ((p.astype(jnp.float32) - delta).astype(p.dtype),
-                m32.astype(dt), v32.astype(dt))
+        def upd(p, g, m, v):
+            g32 = g.astype(jnp.float32)
+            m32 = b1 * m.astype(jnp.float32) + (1 - b1) * g32
+            v32 = b2 * v.astype(jnp.float32) + (1 - b2) * g32 * g32
+            mh = m32 / bc1
+            vh = v32 / bc2
+            delta = lr * (mh / (jnp.sqrt(vh) + cfg.eps) +
+                          cfg.weight_decay * p.astype(jnp.float32))
+            return ((p.astype(jnp.float32) - delta).astype(p.dtype),
+                    m32.astype(dt), v32.astype(dt))
 
-    flat_p, tdef = jax.tree_util.tree_flatten(params)
-    flat_g = jax.tree_util.tree_leaves(grads)
-    flat_m = jax.tree_util.tree_leaves(state.m)
-    flat_v = jax.tree_util.tree_leaves(state.v)
-    out = [upd(p, g, m, v) for p, g, m, v in
-           zip(flat_p, flat_g, flat_m, flat_v)]
-    new_p = jax.tree_util.tree_unflatten(tdef, [o[0] for o in out])
-    new_m = jax.tree_util.tree_unflatten(tdef, [o[1] for o in out])
-    new_v = jax.tree_util.tree_unflatten(tdef, [o[2] for o in out])
-    return new_p, AdamState(step, new_m, new_v), gnorm
+        flat_p, tdef = jax.tree_util.tree_flatten(params)
+        flat_g = jax.tree_util.tree_leaves(grads)
+        flat_m = jax.tree_util.tree_leaves(state.m)
+        flat_v = jax.tree_util.tree_leaves(state.v)
+        out = [upd(p, g, m, v) for p, g, m, v in
+               zip(flat_p, flat_g, flat_m, flat_v)]
+        new_p = jax.tree_util.tree_unflatten(tdef, [o[0] for o in out])
+        new_m = jax.tree_util.tree_unflatten(tdef, [o[1] for o in out])
+        new_v = jax.tree_util.tree_unflatten(tdef, [o[2] for o in out])
+        return new_p, AdamState(step, new_m, new_v), gnorm

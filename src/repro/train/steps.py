@@ -36,13 +36,14 @@ def train_state_specs(cfg, opt_cfg: adam.AdamConfig | None = None):
 
 def cross_entropy(logits, targets, *, z_loss=1e-4):
     """fp32 CE with z-loss regularisation (production stability trick)."""
-    logits = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None],
-                               axis=-1).squeeze(-1)
-    ce = lse - gold
-    zl = z_loss * jnp.square(lse)
-    return jnp.mean(ce + zl), jnp.mean(ce)
+    with jax.named_scope("head_loss"):
+        logits = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, targets[..., None],
+                                   axis=-1).squeeze(-1)
+        ce = lse - gold
+        zl = z_loss * jnp.square(lse)
+        return jnp.mean(ce + zl), jnp.mean(ce)
 
 
 def make_loss_fn(cfg):
