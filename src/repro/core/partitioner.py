@@ -37,6 +37,9 @@ from repro.core.nda import NDAResult, run_nda
 from repro.core.search import SearchBackend
 from repro.spans import span
 
+# the forward-kernel tiling a Pallas flash_attention site record carries
+_TILING_KEYS = ("block_q", "block_k", "tiles_total", "tiles_computed")
+
 
 @dataclasses.dataclass(frozen=True)
 class Violation:
@@ -124,8 +127,12 @@ class ShardingPlan:
             "in_specs": [PartitionSpec, ...], "out_specs": [...]}``.
             :meth:`apply` installs these through the models' kernel
             dispatch so sharded sites lower via ``shard_map`` with the
-            plan's specs (docs/kernels.md).  Empty for programs traced
-            without ``use_pallas``.
+            plan's specs (docs/kernels.md).  A Pallas
+            ``flash_attention`` record adds the forward kernel's tiling
+            at the site's per-device shape (``registry.flash_tiling``):
+            ``block_q``, ``block_k``, ``tiles_computed``,
+            ``tiles_total``.  Empty for programs traced without
+            ``use_pallas``.
     """
 
     mesh: MeshSpec
@@ -341,7 +348,8 @@ class ShardingPlan:
                  "in_specs": [list(map(_spec_entry, s))
                               for s in r["in_specs"]],
                  "out_specs": [list(map(_spec_entry, s))
-                               for s in r["out_specs"]]}
+                               for s in r["out_specs"]],
+                 **{k: r[k] for k in _TILING_KEYS if k in r}}
                 for r in self.kernel_sites],
             "schema": 2,
         }
@@ -403,7 +411,8 @@ class ShardingPlan:
                  "in_specs": [_spec_from_entries(s)
                               for s in r["in_specs"]],
                  "out_specs": [_spec_from_entries(s)
-                               for s in r["out_specs"]]}
+                               for s in r["out_specs"]],
+                 **{k: int(r[k]) for k in _TILING_KEYS if k in r}}
                 for r in d.get("kernel_sites", [])],
         )
 
@@ -676,12 +685,20 @@ def kernel_site_records(cm: CostModel,
             # priced as the reference by the cost model; recorded so, since
             # the dispatch refuses an explicit Pallas choice it cannot tile
             impl = "ref"
-        records.append({
+        record = {
             "site": f"{spec.name}:{ordinal}", "op": op_idx,
             "kernel": spec.name,
             "impl": impl,
             "sharded": sharded,
-            "in_specs": in_specs, "out_specs": out_specs})
+            "in_specs": in_specs, "out_specs": out_specs}
+        if spec.name == "flash_attention" and impl == "pallas":
+            t0 = cm.prog.types[op.operands[0]]
+            tiling = kernel_registry.flash_tiling(
+                dims["q_seq"], dims["kv_seq"], dims["head_dim"],
+                bool(op.params.get("causal")),
+                t0.nbytes // max(t0.size, 1))
+            record.update(dataclasses.asdict(tiling))
+        records.append(record)
     return records
 
 

@@ -114,11 +114,10 @@ def _fa_fwd_jit(causal: bool):
             qt = q.transpose(0, 2, 1, 3)
             kt = k.transpose(0, 2, 1, 3)
             vt = v.transpose(0, 2, 1, 3)
+            t = registry.flash_tiling(S, T, hd, causal, q.dtype.itemsize)
             out = flash_attention(
-                qt, kt, vt, causal=causal,
-                block_q=registry.pick_block(S, 128),
-                block_k=registry.pick_block(T, 128),
-                interpret=interpret)
+                qt, kt, vt, causal=causal, block_q=t.block_q,
+                block_k=t.block_k, interpret=interpret)
             return out.transpose(0, 2, 1, 3)
         return _ref_attention_model_layout(q, k, v, causal)
 
@@ -184,9 +183,9 @@ def _legacy_gqa(q, k, v, causal, interpret):
     qt = q.transpose(0, 2, 1, 3)
     kt = jnp.repeat(k.transpose(0, 2, 1, 3), g, axis=1)
     vt = jnp.repeat(v.transpose(0, 2, 1, 3), g, axis=1)
-    bq, bk = registry.pick_block(S, 128), registry.pick_block(T, 128)
-    out = flash_attention(qt, kt, vt, causal=causal, block_q=bq,
-                          block_k=bk, interpret=interpret)
+    t = registry.flash_tiling(S, T, hd, causal, q.dtype.itemsize)
+    out = flash_attention(qt, kt, vt, causal=causal, block_q=t.block_q,
+                          block_k=t.block_k, interpret=interpret)
     return out.transpose(0, 2, 1, 3)
 
 

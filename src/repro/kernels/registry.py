@@ -16,10 +16,12 @@ accelerator code into the analysis layer.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 __all__ = [
-    "KERNEL_PRIM_PREFIX", "KERNELS", "KernelSpec", "MIN_BLOCK",
+    "FlashTiling", "KERNEL_PRIM_PREFIX", "KERNELS", "KernelSpec",
+    "MIN_BLOCK", "flash_last_block", "flash_tiling", "flash_vmem_bytes",
     "kernel_name", "pallas_feasible", "pick_block", "spec_for_prim",
 ]
 
@@ -42,6 +44,99 @@ def pick_block(n: int, target: int) -> int:
     while n % b:
         b -= 1
     return max(b, 1)
+
+
+# Flash-attention forward tiling.  The largest block a side may take,
+# from a sweep of the forward kernel on a TPU v5e chip (PERF.md), and
+# the VMEM a grid step may fill by ``flash_vmem_bytes``: the Mosaic
+# compiler's default scoped limit on a v5e.
+FLASH_MAX_BLOCK = 1024
+FLASH_VMEM_BUDGET = 16 * 2**20
+
+
+@dataclasses.dataclass(frozen=True)
+class FlashTiling:
+    """How the flash-attention forward grid tiles the score matrix.
+
+    Attributes:
+        block_q: rows of a q-block.
+        block_k: rows of a k/v-block.
+        tiles_total: (q-block, k-block) pairs in the grid.
+        tiles_computed: pairs that do work; a causal pair whose first
+            key lies after its last query is skipped.
+    """
+
+    block_q: int
+    block_k: int
+    tiles_total: int
+    tiles_computed: int
+
+
+def flash_last_block(qi, block_q: int, block_k: int):
+    """The last k-block that causal q-block ``qi`` reads: the block of
+    its last query.  Takes ints, or traced ints inside the kernel."""
+    return ((qi + 1) * block_q - 1) // block_k
+
+
+def flash_vmem_bytes(block_q: int, block_k: int, head_dim: int,
+                     dtype_bytes: int) -> int:
+    """VMEM one forward grid step holds, as the budget counts it.
+
+    Double-buffered q, k, v and o tiles with the head dim padded to 128
+    lanes; an f32 copy of the v tile; the f32 score tile; three f32
+    (block_q, head_dim) arrays (the accumulator and its update); six
+    lane-padded f32 (block_q, 1) rows (running max, sum and their
+    updates).  On the shapes checked against the v5e compiler this
+    counts 2-3 MiB more than the compiler allocates.
+    """
+    lanes = -(-head_dim // 128) * 128
+    tiles = 2 * dtype_bytes * lanes * (2 * block_q + 2 * block_k)
+    return tiles + 4 * (block_k * lanes + block_q * block_k +
+                        3 * block_q * lanes + 6 * block_q * 128)
+
+
+def _aligned_block(n: int, target: int, align: int) -> int:
+    """Largest divisor of ``n`` that is ``<= target`` and a multiple of
+    ``align``; 0 when there is none."""
+    for b in range(min(target, n) // align * align, 0, -align):
+        if n % b == 0:
+            return b
+    return 0
+
+
+@functools.lru_cache(maxsize=1024)
+def flash_tiling(q_seq: int, kv_seq: int, head_dim: int, causal: bool,
+                 dtype_bytes: int) -> FlashTiling:
+    """Blocks of the flash-attention forward kernel at one call's shape.
+
+    Each block is the largest divisor of its length that is a multiple
+    of the dtype's sublane tile and at most ``FLASH_MAX_BLOCK``, with the
+    pair within ``FLASH_VMEM_BUDGET`` (the larger target halves until it
+    fits).  Where no such block is larger than ``pick_block(n, 128)``,
+    that block is kept, so the feasibility rule (``MIN_BLOCK``) and
+    every shape that tiled before are unchanged.  Pure: ``kernels.ops``
+    runs with these blocks and the cost model prices the K/V re-reads
+    from ``tiles_computed``.
+    """
+    sublane = max(8, 32 // dtype_bytes)
+    base_q, base_k = pick_block(q_seq, 128), pick_block(kv_seq, 128)
+    tq = tk = FLASH_MAX_BLOCK
+    while True:
+        bq = max(base_q, _aligned_block(q_seq, tq, sublane))
+        bk = max(base_k, _aligned_block(kv_seq, tk, sublane))
+        if max(tq, tk) <= 128 or flash_vmem_bytes(
+                bq, bk, head_dim, dtype_bytes) <= FLASH_VMEM_BUDGET:
+            break
+        if tq >= tk:
+            tq //= 2
+        else:
+            tk //= 2
+    nq, nk = q_seq // bq, kv_seq // bk
+    computed = nq * nk
+    if causal:
+        computed = sum(min(nk, flash_last_block(i, bq, bk) + 1)
+                       for i in range(nq))
+    return FlashTiling(bq, bk, nq * nk, computed)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,10 +243,12 @@ def _fa_bytes(impl, d, params, db):
     io = d["batch"] * d["heads"] * d["head_dim"] * \
         (2.0 * d["q_seq"] + 2.0 * d["kv_seq"]) * db
     if impl == "pallas":
-        # flash streaming: Q and O once; K/V re-read once per q-block
-        nq = max(1, -(-d["q_seq"] // pick_block(d["q_seq"], 128)))
+        # flash streaming: Q and O once; a K and a V block per tile the
+        # kernel computes (a skipped causal tile copies nothing)
+        t = flash_tiling(d["q_seq"], d["kv_seq"], d["head_dim"],
+                         bool(params.get("causal")), db)
         return d["batch"] * d["heads"] * d["head_dim"] * db * (
-            2.0 * d["q_seq"] + 2.0 * d["kv_seq"] * nq)
+            2.0 * d["q_seq"] + 2.0 * t.block_k * t.tiles_computed)
     # reference: materializes the f32 score matrix (write+read, twice —
     # scores then softmax probabilities)
     scores = 4.0 * d["batch"] * d["heads"] * d["q_seq"] * d["kv_seq"] * 4

@@ -105,6 +105,20 @@ class TestKernelSites:
         assert r["impl"] in registry.KERNELS["flash_attention"].impls
         assert len(r["in_specs"]) == 3 and len(r["out_specs"]) == 1
 
+    def test_pallas_site_records_its_tiling(self, attn_plan):
+        """A Pallas flash_attention record carries the forward kernel's
+        tiling at the site's per-device shape."""
+        _, plan = attn_plan
+        r = next(r for r in plan.kernel_sites
+                 if r["kernel"] == "flash_attention")
+        assert r["impl"] == "pallas"
+        # 128 f32 tokens of head dim 32, causal: one 128 x 128 tile
+        t = registry.flash_tiling(128, 128, 32, True, 4)
+        assert (r["block_q"], r["block_k"], r["tiles_total"],
+                r["tiles_computed"]) == (t.block_q, t.block_k,
+                                         t.tiles_total, t.tiles_computed)
+        assert (t.tiles_total, t.tiles_computed) == (1, 1)
+
     def test_blocked_roles_never_sharded(self, attn_plan):
         _, plan = attn_plan
         for r in plan.kernel_sites:

@@ -17,7 +17,8 @@ try:
 except ImportError:            # optional test extra; see pyproject.toml
     given = settings = st = None
 
-from repro.kernels import ops, ref
+from repro.configs import ARCH_IDS, cells, get_config
+from repro.kernels import ops, ref, registry
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rg_lru import rg_lru_scan
 
@@ -126,6 +127,165 @@ class TestFlashAttention:
         ).transpose(0, 2, 1, 3)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
+
+
+def _check_attention(q, k, v, causal, dtype, **blocks):
+    out = flash_attention(q, k, v, causal=causal, interpret=True, **blocks)
+    want = ref.reference_attention(q, k, v, causal=causal)
+    assert out.dtype == dtype
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+
+
+class TestFlashTilingKernel:
+    """The kernel at the blocks ``registry.flash_tiling`` picks."""
+
+    @pytest.mark.parametrize("S,T,hd,causal,dtype,blocks", [
+        # a skipped tile, a diagonal-free tile and two diagonal tiles
+        (2048, 2048, 64, True, jnp.float32, (1024, 1024)),
+        (1024, 1024, 64, True, jnp.bfloat16, (1024, 1024)),
+        (1024, 1024, 64, False, jnp.bfloat16, (1024, 1024)),
+        # S != T, non-causal, block_q < block_k and block_q > block_k
+        (256, 2048, 64, False, jnp.float32, (256, 1024)),
+        (2048, 256, 32, False, jnp.bfloat16, (1024, 256)),
+        # causal with S != T (absolute positions): a skipped tile with
+        # block_q < block_k; a diagonal-free tile with block_q > block_k
+        (384, 2048, 32, True, jnp.float32, (384, 1024)),
+        (2048, 384, 32, True, jnp.bfloat16, (1024, 384)),
+    ])
+    def test_matches_reference(self, S, T, hd, causal, dtype, blocks):
+        t = registry.flash_tiling(S, T, hd, causal, jnp.dtype(dtype).itemsize)
+        assert (t.block_q, t.block_k) == blocks
+        key = jax.random.PRNGKey(S + T + hd)
+        q = rand(key, (1, 2, S, hd), dtype)
+        k = rand(jax.random.fold_in(key, 1), (1, 2, T, hd), dtype)
+        v = rand(jax.random.fold_in(key, 2), (1, 2, T, hd), dtype)
+        _check_attention(q, k, v, causal, dtype, block_q=t.block_q,
+                         block_k=t.block_k)
+
+    @pytest.mark.parametrize("S,hd,bq,bk", [
+        (1024, 64, 512, 512),        # skip, diagonal-free and diagonal
+        (1536, 32, 256, 512),
+        (1536, 32, 512, 256),
+        (1536, 32, 128, 384),
+    ])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_causal_explicit_blocks(self, S, hd, bq, bk, dtype):
+        """Causal at explicit blocks smaller than the tiling picks, so
+        every kind of tile occurs, square and uneven both ways."""
+        key = jax.random.PRNGKey(bq + bk)
+        q, k, v = (rand(jax.random.fold_in(key, i), (1, 1, S, hd), dtype)
+                   for i in range(3))
+        _check_attention(q, k, v, True, dtype, block_q=bq, block_k=bk)
+
+    def test_dispatch_runs_tiled_kernel(self):
+        """``ops.attention`` forced to Pallas runs the tiled kernel."""
+        from repro.models.sharding import KernelDispatch, kernel_dispatch
+        key = jax.random.PRNGKey(41)
+        q, k, v = (rand(jax.random.fold_in(key, i), (1, 1024, 2, 64),
+                        jnp.bfloat16) for i in range(3))
+        with kernel_dispatch(KernelDispatch(default_impl="pallas")):
+            out = ops.attention(q, k, v, causal=True)
+        want = ref.reference_attention(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), causal=True).transpose(0, 2, 1, 3)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=2e-2, atol=2e-2)
+
+
+def _attention_shapes():
+    """(S, T, head_dim, causal) of every fused attention call of every
+    config at its traffic lengths, plus the benchmark's cells."""
+    out = {(1024, 1024, 64, True), (2048, 2048, 96, True)}
+    for arch in ARCH_IDS:
+        cfg = get_config(arch)
+        if "attn" not in cfg.pattern or cfg.sliding_window:
+            continue
+        hd = cfg.resolved_head_dim
+        for shape in cells(arch):
+            if shape.kind == "decode":
+                continue
+            n = shape.seq_len
+            if cfg.is_encoder_decoder:
+                n //= 2
+                out.add((n, n, hd, False))
+            out.add((n, n, hd, True))
+    return sorted(out)
+
+
+def _brute_tiles(S, T, bq, bk, causal):
+    """Tiles of the score matrix with at least one unmasked entry."""
+    if not causal:
+        return (S // bq) * (T // bk)
+    keep = np.arange(S)[:, None] >= np.arange(T)[None, :]
+    return int(keep.reshape(S // bq, bq, T // bk, bk).any(axis=(1, 3)).sum())
+
+
+class TestFlashTiling:
+    """``registry.flash_tiling``: pure, and the same answer for every
+    shape the parent's rule tiled."""
+
+    @pytest.mark.parametrize("S,T,hd,causal", _attention_shapes() + [
+        (1500, 1500, 64, False), (131, 131, 32, True), (96, 96, 16, True),
+        (256, 1024, 64, False), (1024, 384, 32, True), (1200, 1200, 64, True),
+    ])
+    @pytest.mark.parametrize("dtype_bytes", [2, 4])
+    def test_blocks(self, S, T, hd, causal, dtype_bytes):
+        t = registry.flash_tiling(S, T, hd, causal, dtype_bytes)
+        sublane = 32 // dtype_bytes
+        for n, b in ((S, t.block_q), (T, t.block_k)):
+            assert n % b == 0
+            old = registry.pick_block(n, 128)
+            # larger than the parent's block only where aligned
+            assert b == old or (b > old and b % sublane == 0)
+        assert t.tiles_total == (S // t.block_q) * (T // t.block_k)
+        assert t.tiles_computed == _brute_tiles(S, T, t.block_q, t.block_k,
+                                                causal)
+        if max(t.block_q, t.block_k) > 128:
+            assert registry.flash_vmem_bytes(
+                t.block_q, t.block_k, hd,
+                dtype_bytes) <= registry.FLASH_VMEM_BUDGET
+        # the parent's feasibility rule, unchanged
+        dims = {"batch": 1, "heads": 1, "q_seq": S, "kv_seq": T,
+                "head_dim": hd}
+        assert registry.pallas_feasible("flash_attention", dims) == (
+            registry.pick_block(S, 128) >= registry.MIN_BLOCK and
+            registry.pick_block(T, 128) >= registry.MIN_BLOCK)
+
+    @pytest.mark.parametrize("S,hd,want", [
+        # qwen2_05b.train.s1k: 112 grid steps a call at (8, 14, 1024,
+        # 64), against 7168 at the parent's 128-blocks
+        (1024, 64, (1024, 1024, 1, 1)),
+        (2048, 96, (1024, 1024, 3, 4)),          # phi3_mini
+        (32768, 128, (1024, 1024, 528, 1024)),   # prefill_32k at hd 128
+    ])
+    def test_config_tilings(self, S, hd, want):
+        t = registry.flash_tiling(S, S, hd, True, 2)
+        assert (t.block_q, t.block_k, t.tiles_computed,
+                t.tiles_total) == want
+
+    def test_budget_halves_larger_side(self):
+        """hd 256 in f32 does not fit 1024 x 1024: the q side halves."""
+        t = registry.flash_tiling(4096, 4096, 256, True, 4)
+        assert (t.block_q, t.block_k) == (512, 1024)
+        assert registry.flash_vmem_bytes(
+            1024, 1024, 256, 4) > registry.FLASH_VMEM_BUDGET
+
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_bytes_count_computed_tiles(self, causal):
+        """The cost model's Pallas bytes re-read a K and a V block per
+        tile the kernel computes."""
+        d = {"batch": 2, "heads": 3, "q_seq": 2048, "kv_seq": 2048,
+             "head_dim": 96}
+        t = registry.flash_tiling(2048, 2048, 96, causal, 2)
+        want = 2 * 3 * 96 * 2 * (2 * 2048 + 2 * t.block_k * t.tiles_computed)
+        got = registry.KERNELS["flash_attention"].bytes_moved(
+            "pallas", d, {"causal": causal}, 2)
+        assert got == want
+        assert t.tiles_computed == (3 if causal else 4)
 
 
 class TestRGLRU:

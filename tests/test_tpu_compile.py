@@ -77,6 +77,59 @@ def test_flash_attention_compiles_at_qwen2_05b_width(one_chip, dtype):
     assert _holds_kernel(compiled)
 
 
+def _compile_flash(sharding, shape, dtype, causal=True):
+    B, H, S, hd = shape
+    t = registry.flash_tiling(S, S, hd, causal, jnp.dtype(dtype).itemsize)
+    q = _sds(shape, dtype, sharding)
+    compiled = jax.jit(
+        lambda q, k, v: flash_attention(q, k, v, causal=causal,
+                                        block_q=t.block_q, block_k=t.block_k,
+                                        interpret=False)
+    ).lower(q, q, q).compile()
+    assert _holds_kernel(compiled)
+    return t
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((8, 14, 1024, 64), jnp.bfloat16),   # qwen2_05b.train.s1k
+    ((4, 16, 2048, 96), jnp.bfloat16),   # phi3_mini per device on 2x2
+    ((1, 8, 4096, 128), jnp.bfloat16),   # llama3, qwen15_32b, arctic
+    ((1, 8, 4096, 128), jnp.float32),
+    ((1, 2, 4096, 256), jnp.bfloat16),
+    ((1, 2, 4096, 256), jnp.float32),    # over budget at 1024: halves
+])
+def test_flash_attention_compiles_at_picked_tiling(one_chip, shape, dtype):
+    """The blocks ``registry.flash_tiling`` picks compile (Mosaic refuses
+    a kernel whose VMEM overflows its scoped limit)."""
+    t = _compile_flash(one_chip, shape, dtype)
+    assert t.block_q > 128 and t.block_k > 128
+    assert registry.flash_vmem_bytes(
+        t.block_q, t.block_k, shape[3],
+        jnp.dtype(dtype).itemsize) <= registry.FLASH_VMEM_BUDGET
+
+
+def test_flash_attention_compiles_at_every_config_shape(one_chip):
+    """Every config's fused attention at its train and prefill lengths
+    (whisper's encoder and decoder at half of them): where the tiling
+    differs from the parent's 128-blocks, it compiles."""
+    from repro.configs import ARCH_IDS, cells, get_config
+    shapes = set()
+    for arch in ARCH_IDS:
+        cfg = get_config(arch)
+        if "attn" not in cfg.pattern or cfg.sliding_window:
+            continue
+        for shape in cells(arch):
+            if shape.kind != "decode":
+                n = shape.seq_len // (2 if cfg.is_encoder_decoder else 1)
+                shapes.add((n, cfg.resolved_head_dim))
+    for S, hd in sorted(shapes):
+        for causal in (True, False):
+            t = registry.flash_tiling(S, S, hd, causal, 2)
+            if (t.block_q, t.block_k) == (registry.pick_block(S, 128),) * 2:
+                continue
+            _compile_flash(one_chip, (1, 2, S, hd), jnp.bfloat16, causal)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_rg_lru_compiles_at_recurrentgemma_width(one_chip, dtype):
     """recurrentgemma_2b's RG-LRU as ``layers.rglru_apply`` calls it:
