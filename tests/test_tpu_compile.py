@@ -191,3 +191,27 @@ def test_fused_train_step_plan_compiles_on_2x2(mesh22, monkeypatch):
     with jax.set_mesh(mesh22):
         compiled = plan.apply(fn, mesh22).lower(*args).compile()
     assert _holds_kernel(compiled)
+
+
+def test_phi3_mini_2x2_cell_plan_fits_a_v5e_chip(mesh22, monkeypatch):
+    """The benchmark's ``phi3_mini.train.2x2`` step as its harness builds
+    it (published widths at 16 layers, 8 x 2048 tokens, MCTS seed 0 on
+    ``data`` x ``model`` = 2 x 2) plans with both flash sites fused and
+    sharded, lowers them through ``shard_map``, and compiles at all 16
+    layers to under 16 GiB a device by ``memory_analysis()``."""
+    from perfbench import catalog, harness
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    bench = catalog.benchmark()
+    cell = catalog.workload(bench, "phi3_mini.train.2x2")
+    conf = catalog.config(bench, cell["config"])
+    mix = catalog.traffic(cell["traffic"])
+    assert (conf["num_hidden_layers"], mix["batch"], mix["seq_len"],
+            mix["mesh"], mix["search"]) == (
+        16, 8, 2048, [2, 2], {"backend": "mcts", "seed": 0})
+    tc = harness.TrainCell(conf, mix, list(mesh22.devices.flat))
+    assert tc.plan.evaluations > 0
+    assert [(r["impl"], r["sharded"]) for r in tc.plan.kernel_sites] == \
+        [("pallas", True)] * 2
+    assert tc.kernels_shard_mapped
+    assert tc.memory_analysis()["step_bytes"] < 16 * 2**30
