@@ -37,8 +37,11 @@ from repro.core.nda import NDAResult, run_nda
 from repro.core.search import SearchBackend
 from repro.spans import span
 
-# the forward-kernel tiling a Pallas flash_attention site record carries
-_TILING_KEYS = ("block_q", "block_k", "tiles_total", "tiles_computed")
+# the forward and backward tilings a Pallas flash_attention site record
+# carries (beside the backward's impl, ``bwd_impl``)
+_TILING_KEYS = ("block_q", "block_k", "tiles_total", "tiles_computed",
+                "bwd_block_q", "bwd_block_k", "bwd_tiles_total",
+                "bwd_tiles_computed")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,7 +134,10 @@ class ShardingPlan:
             ``flash_attention`` record adds the forward kernel's tiling
             at the site's per-device shape (``registry.flash_tiling``):
             ``block_q``, ``block_k``, ``tiles_computed``,
-            ``tiles_total``.  Empty for programs traced without
+            ``tiles_total``; and the backward's impl and tiling
+            (``registry.flash_bwd_tiling``): ``bwd_impl``,
+            ``bwd_block_q``, ``bwd_block_k``, ``bwd_tiles_computed``,
+            ``bwd_tiles_total``.  Empty for programs traced without
             ``use_pallas``.
     """
 
@@ -349,7 +355,8 @@ class ShardingPlan:
                               for s in r["in_specs"]],
                  "out_specs": [list(map(_spec_entry, s))
                                for s in r["out_specs"]],
-                 **{k: r[k] for k in _TILING_KEYS if k in r}}
+                 **{k: r[k] for k in (*_TILING_KEYS, "bwd_impl")
+                    if k in r}}
                 for r in self.kernel_sites],
             "schema": 2,
         }
@@ -412,7 +419,9 @@ class ShardingPlan:
                               for s in r["in_specs"]],
                  "out_specs": [_spec_from_entries(s)
                                for s in r["out_specs"]],
-                 **{k: int(r[k]) for k in _TILING_KEYS if k in r}}
+                 **{k: int(r[k]) for k in _TILING_KEYS if k in r},
+                 **({"bwd_impl": r["bwd_impl"]} if "bwd_impl" in r
+                    else {})}
                 for r in d.get("kernel_sites", [])],
         )
 
@@ -693,11 +702,15 @@ def kernel_site_records(cm: CostModel,
             "in_specs": in_specs, "out_specs": out_specs}
         if spec.name == "flash_attention" and impl == "pallas":
             t0 = cm.prog.types[op.operands[0]]
-            tiling = kernel_registry.flash_tiling(
-                dims["q_seq"], dims["kv_seq"], dims["head_dim"],
-                bool(op.params.get("causal")),
-                t0.nbytes // max(t0.size, 1))
-            record.update(dataclasses.asdict(tiling))
+            shape = (dims["q_seq"], dims["kv_seq"], dims["head_dim"],
+                     bool(op.params.get("causal")),
+                     t0.nbytes // max(t0.size, 1))
+            record.update(dataclasses.asdict(
+                kernel_registry.flash_tiling(*shape)))
+            # the backward runs where its forward site runs
+            record["bwd_impl"] = impl
+            record.update({f"bwd_{k}": v for k, v in dataclasses.asdict(
+                kernel_registry.flash_bwd_tiling(*shape)).items()})
         records.append(record)
     return records
 

@@ -14,7 +14,9 @@ Model code (``use_pallas=True`` paths) calls :func:`attention` /
   of inlining the kernel internals;
 - is differentiable: a ``jax.custom_vjp`` routes the backward pass
   through its own named jit (``toast_kernel__..._bwd``), so train steps
-  trace to fused forward *and* backward ops;
+  trace to fused forward *and* backward ops; flash attention's backward
+  runs the implementation its forward site resolved to (RG-LRU's runs
+  the reference);
 - optionally lowers through ``shard_map`` when the dispatch state
   carries the plan's per-site partition specs (``plan.apply`` installs
   them), so sharded kernel sites execute as per-device Pallas calls
@@ -30,7 +32,8 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels import registry
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import (flash_attention,
+                                           flash_attention_bwd)
 from repro.kernels.registry import MIN_BLOCK
 from repro.kernels.rg_lru import rg_lru_scan
 
@@ -127,10 +130,23 @@ def _fa_fwd_jit(causal: bool):
 
 @lru_cache(maxsize=None)
 def _fa_bwd_jit(causal: bool):
-    """Named backward jit — traces as ``kernel:flash_attention_bwd``."""
+    """Named backward jit — traces as ``kernel:flash_attention_bwd``.
 
-    def bwd(q, k, v, g):
+    Runs the Pallas backward where the forward site resolved to Pallas,
+    else the vjp of the reference.
+    """
+
+    def bwd(q, k, v, g, impl, interpret):
         with jax.named_scope("attn_bwd"):
+            if impl == "pallas":
+                B, S, H, hd = q.shape
+                t = registry.flash_bwd_tiling(S, k.shape[1], hd, causal,
+                                              q.dtype.itemsize)
+                grads = flash_attention_bwd(
+                    *(x.transpose(0, 2, 1, 3) for x in (q, k, v, g)),
+                    causal=causal, block_q=t.block_q, block_k=t.block_k,
+                    interpret=interpret)
+                return tuple(x.transpose(0, 2, 1, 3) for x in grads)
             _, vjp = jax.vjp(
                 lambda q_, k_, v_: _ref_attention_model_layout(
                     q_, k_, v_, causal), q, k, v)
@@ -138,7 +154,7 @@ def _fa_bwd_jit(causal: bool):
 
     bwd.__name__ = \
         f"toast_kernel__flash_attention_bwd__causal={int(causal)}"
-    return jax.jit(bwd)
+    return jax.jit(bwd, static_argnums=(4, 5))
 
 
 @lru_cache(maxsize=None)
@@ -154,7 +170,7 @@ def _attention_core(causal: bool, impl: str, interpret: bool):
         return fwd_jit(q, k, v, impl, interpret), (q, k, v)
 
     def fa_bwd(res, g):
-        return bwd_jit(*res, g)
+        return bwd_jit(*res, g, impl, interpret)
 
     fa.defvjp(fa_fwd, fa_bwd)
     return fa

@@ -21,7 +21,8 @@ import math
 
 __all__ = [
     "FlashTiling", "KERNEL_PRIM_PREFIX", "KERNELS", "KernelSpec",
-    "MIN_BLOCK", "flash_last_block", "flash_tiling", "flash_vmem_bytes",
+    "MIN_BLOCK", "flash_bwd_tiling", "flash_bwd_vmem_bytes",
+    "flash_last_block", "flash_tiling", "flash_vmem_bytes",
     "kernel_name", "pallas_feasible", "pick_block", "spec_for_prim",
 ]
 
@@ -104,6 +105,58 @@ def _aligned_block(n: int, target: int, align: int) -> int:
     return 0
 
 
+def flash_bwd_vmem_bytes(block_q: int, block_k: int, head_dim: int,
+                         dtype_bytes: int) -> int:
+    """VMEM one grid step of the flash-attention backward holds, as the
+    budget counts it: the larger of its ``dkv`` and ``dq`` passes
+    (``stats`` holds less than either).
+
+    Both hold double-buffered q, dO, k and v tiles and their outputs
+    (dK and dV, or dQ) with the head dim padded to 128 lanes, and their
+    f32 accumulators; the score-sized temporaries count as one f32 tile
+    and P and dS in the input dtype, and the f32 statistics blocks as
+    (8, block_q) twice and their (block_q, 128) columns.  At hd 256 in
+    f32 and 1024 x 1024 this counts 26.6 MiB where the v5e compiler
+    allocates 23.7.
+    """
+    lanes = -(-head_dim // 128) * 128
+    squares = block_q * block_k * (4 + 2 * dtype_bytes)
+    stats = 2 * 4 * 8 * block_q + 4 * block_q * 128
+    dkv = 2 * dtype_bytes * lanes * (2 * block_q + 4 * block_k) + \
+        2 * 4 * block_k * lanes
+    dq = 2 * dtype_bytes * lanes * (3 * block_q + 2 * block_k) + \
+        4 * block_q * lanes
+    return squares + stats + max(dkv, dq)
+
+
+def _fit_tiling(q_seq: int, kv_seq: int, head_dim: int, causal: bool,
+                dtype_bytes: int, vmem_bytes, max_q: int = FLASH_MAX_BLOCK,
+                max_k: int = FLASH_MAX_BLOCK) -> FlashTiling:
+    """The largest sublane-aligned blocks up to ``max_q`` / ``max_k``
+    whose ``vmem_bytes`` count stays within ``FLASH_VMEM_BUDGET``,
+    halving the larger target until it does; ``pick_block(n, 128)``
+    where nothing aligned is larger."""
+    sublane = max(8, 32 // dtype_bytes)
+    base_q, base_k = pick_block(q_seq, 128), pick_block(kv_seq, 128)
+    tq, tk = max_q, max_k
+    while True:
+        bq = max(base_q, _aligned_block(q_seq, tq, sublane))
+        bk = max(base_k, _aligned_block(kv_seq, tk, sublane))
+        if max(tq, tk) <= 128 or vmem_bytes(
+                bq, bk, head_dim, dtype_bytes) <= FLASH_VMEM_BUDGET:
+            break
+        if tq >= tk:
+            tq //= 2
+        else:
+            tk //= 2
+    nq, nk = q_seq // bq, kv_seq // bk
+    computed = nq * nk
+    if causal:
+        computed = sum(min(nk, flash_last_block(i, bq, bk) + 1)
+                       for i in range(nq))
+    return FlashTiling(bq, bk, nq * nk, computed)
+
+
 @functools.lru_cache(maxsize=1024)
 def flash_tiling(q_seq: int, kv_seq: int, head_dim: int, causal: bool,
                  dtype_bytes: int) -> FlashTiling:
@@ -118,25 +171,31 @@ def flash_tiling(q_seq: int, kv_seq: int, head_dim: int, causal: bool,
     runs with these blocks and the cost model prices the K/V re-reads
     from ``tiles_computed``.
     """
-    sublane = max(8, 32 // dtype_bytes)
-    base_q, base_k = pick_block(q_seq, 128), pick_block(kv_seq, 128)
-    tq = tk = FLASH_MAX_BLOCK
-    while True:
-        bq = max(base_q, _aligned_block(q_seq, tq, sublane))
-        bk = max(base_k, _aligned_block(kv_seq, tk, sublane))
-        if max(tq, tk) <= 128 or flash_vmem_bytes(
-                bq, bk, head_dim, dtype_bytes) <= FLASH_VMEM_BUDGET:
-            break
-        if tq >= tk:
-            tq //= 2
-        else:
-            tk //= 2
-    nq, nk = q_seq // bq, kv_seq // bk
-    computed = nq * nk
-    if causal:
-        computed = sum(min(nk, flash_last_block(i, bq, bk) + 1)
-                       for i in range(nq))
-    return FlashTiling(bq, bk, nq * nk, computed)
+    return _fit_tiling(q_seq, kv_seq, head_dim, causal, dtype_bytes,
+                       flash_vmem_bytes)
+
+
+@functools.lru_cache(maxsize=1024)
+def flash_bwd_tiling(q_seq: int, kv_seq: int, head_dim: int, causal: bool,
+                     dtype_bytes: int) -> FlashTiling:
+    """Blocks of the flash-attention backward at one call's shape.
+
+    The forward's rule (:func:`flash_tiling`) with the backward's own
+    VMEM count (:func:`flash_bwd_vmem_bytes`), and for causal calls no
+    block over half its length, so that whole tiles lie past the
+    diagonal and are skipped: at (8, 14, 1024, 64) 512 x 512 blocks
+    took 1.83 ms a call against 1.96 at 1024 x 1024 on a v5e, and at
+    (4, 32, 2048, 96) 1024 x 1024 took 6.96 ms against 7.44 at 512 x 512
+    (PERF.md).  The three passes share the blocks, and
+    ``tiles_computed`` counts the tiles each of them computes.  Pure;
+    the cost model does not read it (it prices the backward as the
+    reference).
+    """
+    half = 2 if causal else 1
+    return _fit_tiling(q_seq, kv_seq, head_dim, causal, dtype_bytes,
+                       flash_bwd_vmem_bytes,
+                       max_q=min(FLASH_MAX_BLOCK, q_seq // half),
+                       max_k=min(FLASH_MAX_BLOCK, kv_seq // half))
 
 
 @dataclasses.dataclass(frozen=True)

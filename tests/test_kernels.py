@@ -19,7 +19,8 @@ except ImportError:            # optional test extra; see pyproject.toml
 
 from repro.configs import ARCH_IDS, cells, get_config
 from repro.kernels import ops, ref, registry
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import (flash_attention,
+                                           flash_attention_bwd)
 from repro.kernels.rg_lru import rg_lru_scan
 
 
@@ -255,6 +256,31 @@ class TestFlashTiling:
             registry.pick_block(S, 128) >= registry.MIN_BLOCK and
             registry.pick_block(T, 128) >= registry.MIN_BLOCK)
 
+    @pytest.mark.parametrize("S,T,hd,causal", _attention_shapes() + [
+        (1500, 1500, 64, False), (131, 131, 32, True), (96, 96, 16, True),
+        (256, 1024, 64, False), (1024, 384, 32, True), (1200, 1200, 64, True),
+    ])
+    @pytest.mark.parametrize("dtype_bytes", [2, 4])
+    def test_bwd_blocks(self, S, T, hd, causal, dtype_bytes):
+        """``flash_bwd_tiling``: blocks divide the shape, aligned where
+        larger than the parent's, no larger than the forward's, within
+        the VMEM budget, and the tile counts are those of the grid."""
+        t = registry.flash_bwd_tiling(S, T, hd, causal, dtype_bytes)
+        fwd = registry.flash_tiling(S, T, hd, causal, dtype_bytes)
+        sublane = 32 // dtype_bytes
+        for n, b, f in ((S, t.block_q, fwd.block_q),
+                        (T, t.block_k, fwd.block_k)):
+            assert n % b == 0 and b <= f
+            old = registry.pick_block(n, 128)
+            assert b == old or (b > old and b % sublane == 0)
+        assert t.tiles_total == (S // t.block_q) * (T // t.block_k)
+        assert t.tiles_computed == _brute_tiles(S, T, t.block_q, t.block_k,
+                                                causal)
+        if max(t.block_q, t.block_k) > 128:
+            assert registry.flash_bwd_vmem_bytes(
+                t.block_q, t.block_k, hd,
+                dtype_bytes) <= registry.FLASH_VMEM_BUDGET
+
     @pytest.mark.parametrize("S,hd,want", [
         # qwen2_05b.train.s1k: 112 grid steps a call at (8, 14, 1024,
         # 64), against 7168 at the parent's 128-blocks
@@ -286,6 +312,130 @@ class TestFlashTiling:
             "pallas", d, {"causal": causal}, 2)
         assert got == want
         assert t.tiles_computed == (3 if causal else 4)
+
+
+def _pallas_names(jaxpr) -> list[str]:
+    """Names of the ``pallas_call``s in a jaxpr, nested jaxprs too."""
+    out = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            out.append(e.params["name"])
+        for p in e.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else [p]:
+                if hasattr(sub, "eqns"):
+                    out += _pallas_names(sub)
+                elif hasattr(getattr(sub, "jaxpr", None), "eqns"):
+                    out += _pallas_names(sub.jaxpr)
+    return out
+
+
+def _model_layout_grads(q, k, v, g, causal, dispatch):
+    """``(dq, dk, dv)`` of ``ops.attention`` under ``dispatch``, and the
+    names of the Pallas calls its backward runs."""
+    from repro.models.sharding import kernel_dispatch
+
+    def attn(q, k, v):
+        return ops.attention(q, k, v, causal=causal)
+
+    with kernel_dispatch(dispatch):
+        _, vjp = jax.vjp(attn, q, k, v)
+        jaxpr = jax.make_jaxpr(vjp)(g)
+        grads = vjp(g)
+    return grads, _pallas_names(jaxpr.jaxpr)
+
+
+def _reference_grads(q, k, v, g, causal):
+    """The vjp of ``ref.reference_attention``, in the layout given."""
+    _, vjp = jax.vjp(lambda a, b, c: ref.reference_attention(
+        a, b, c, causal=causal), q, k, v)
+    return vjp(g)
+
+
+def _check_grads(got, want, dtype):
+    """Each gradient within a share of its largest entry.  In bf16 the
+    reference also rounds the scores and dP to bf16, where the kernel
+    keeps them f32: each lies within about 1% of an f32 computation."""
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype == dtype
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(a - b).max() <= tol * np.abs(b).max()
+
+
+class TestFlashAttentionBwd:
+    """The Pallas backward against the vjp of the reference."""
+
+    @pytest.mark.parametrize("S,hd,causal,dtype", [
+        (256, 64, True, jnp.float32),
+        (256, 64, False, jnp.bfloat16),
+        (256, 96, True, jnp.bfloat16),
+        (256, 96, False, jnp.float32),
+        # qwen2_05b's head size at 2048 tokens, at 1024-blocks
+        (2048, 64, True, jnp.bfloat16),
+    ])
+    def test_dispatch_matches_reference_vjp(self, S, hd, causal, dtype):
+        """``ops.attention`` forced to Pallas runs the three backward
+        passes at the blocks ``registry.flash_bwd_tiling`` picks."""
+        from repro.models.sharding import KernelDispatch
+        key = jax.random.PRNGKey(S + hd)
+        q, k, v, g = (rand(jax.random.fold_in(key, i), (1, S, 2, hd), dtype)
+                      for i in range(4))
+        got, names = _model_layout_grads(
+            q, k, v, g, causal, KernelDispatch(default_impl="pallas"))
+        prefix = f"toast_kernel__flash_attention_bwd__causal_{int(causal)}"
+        assert sorted(names) == sorted(
+            f"{prefix}_{p}" for p in ("stats", "dkv", "dq"))
+        t = [x.transpose(0, 2, 1, 3) for x in (q, k, v, g)]
+        want = [x.transpose(0, 2, 1, 3)
+                for x in _reference_grads(*t, causal)]
+        _check_grads(got, want, dtype)
+        # causal blocks of at most half the length: tiles are skipped
+        t = registry.flash_bwd_tiling(S, S, hd, causal,
+                                      jnp.dtype(dtype).itemsize)
+        assert (t.tiles_computed < t.tiles_total) == causal
+
+    @pytest.mark.parametrize("S,T,bq,bk", [
+        (512, 512, 128, 256),       # skipped, diagonal-free, diagonal
+        (512, 512, 256, 128),
+        (512, 512, 128, 128),
+        (128, 512, 64, 128),        # keys past every query: dK = dV = 0
+        (512, 128, 128, 64),        # queries past every key
+    ])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_causal_explicit_blocks(self, S, T, bq, bk, dtype):
+        """Causal at explicit blocks, square and uneven both ways, and
+        at S != T (absolute positions), so every kind of tile occurs."""
+        key = jax.random.PRNGKey(bq + 3 * bk + T)
+        q, g = (rand(jax.random.fold_in(key, i), (1, 2, S, 32), dtype)
+                for i in (0, 3))
+        k, v = (rand(jax.random.fold_in(key, i), (1, 2, T, 32), dtype)
+                for i in (1, 2))
+        got = flash_attention_bwd(q, k, v, g, causal=True, block_q=bq,
+                                  block_k=bk, interpret=True)
+        _check_grads(got, _reference_grads(q, k, v, g, True), dtype)
+
+    @pytest.mark.parametrize("S,dispatch", [
+        (256, {}),                             # CPU: nothing asks for Pallas
+        (131, {"default_impl": "pallas"}),     # cannot tile: refused
+    ])
+    def test_backward_is_reference_where_forward_is(self, S, dispatch):
+        """Where the forward site resolves to the reference, so does the
+        backward: no Pallas call, the reference's gradients."""
+        from repro.models.sharding import KernelDispatch
+        key = jax.random.PRNGKey(S)
+        q, k, v, g = (rand(jax.random.fold_in(key, i), (1, S, 2, 32))
+                      for i in range(4))
+        d = KernelDispatch(**dispatch)
+        if dispatch:
+            # the explicit Pallas choice raises at the forward ...
+            with pytest.raises(ValueError, match="no divisor block"):
+                _model_layout_grads(q, k, v, g, True, d)
+            d = KernelDispatch()         # ... and the automatic one runs ref
+        got, names = _model_layout_grads(q, k, v, g, True, d)
+        assert names == []
+        t = [x.transpose(0, 2, 1, 3) for x in (q, k, v, g)]
+        want = [x.transpose(0, 2, 1, 3) for x in _reference_grads(*t, True)]
+        _check_grads(got, want, jnp.float32)
 
 
 class TestRGLRU:

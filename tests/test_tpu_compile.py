@@ -24,7 +24,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import ops, registry
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import (flash_attention,
+                                           flash_attention_bwd)
 from repro.kernels.rg_lru import rg_lru_scan
 from repro.models.sharding import KernelDispatch, kernel_dispatch
 
@@ -66,6 +67,14 @@ def _holds_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
 
+BWD = "toast_kernel__flash_attention_bwd__causal_1"
+
+
+def _holds_backward(text: str) -> bool:
+    """The three Pallas passes of the causal backward are in the HLO."""
+    return all(f"{BWD}_{p}" in text for p in ("stats", "dkv", "dq"))
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_flash_attention_compiles_at_qwen2_05b_width(one_chip, dtype):
     """qwen2_05b attention: 14 heads of 64, 1024 tokens."""
@@ -90,14 +99,43 @@ def _compile_flash(sharding, shape, dtype, causal=True):
     return t
 
 
-@pytest.mark.parametrize("shape,dtype", [
+def _compile_flash_bwd(sharding, shape, dtype, causal=True):
+    B, H, S, hd = shape
+    t = registry.flash_bwd_tiling(S, S, hd, causal,
+                                  jnp.dtype(dtype).itemsize)
+    q = _sds(shape, dtype, sharding)
+    compiled = jax.jit(
+        lambda q, k, v, g: flash_attention_bwd(
+            q, k, v, g, causal=causal, block_q=t.block_q,
+            block_k=t.block_k, interpret=False)
+    ).lower(q, q, q, q).compile()
+    assert _holds_kernel(compiled)
+    return t
+
+
+PICKED_SHAPES = [
     ((8, 14, 1024, 64), jnp.bfloat16),   # qwen2_05b.train.s1k
     ((4, 16, 2048, 96), jnp.bfloat16),   # phi3_mini per device on 2x2
     ((1, 8, 4096, 128), jnp.bfloat16),   # llama3, qwen15_32b, arctic
     ((1, 8, 4096, 128), jnp.float32),
     ((1, 2, 4096, 256), jnp.bfloat16),
     ((1, 2, 4096, 256), jnp.float32),    # over budget at 1024: halves
-])
+]
+
+
+@pytest.mark.parametrize("shape,dtype", PICKED_SHAPES)
+def test_flash_attention_bwd_compiles_at_picked_tiling(one_chip, shape,
+                                                       dtype):
+    """The backward's three passes compile at the blocks
+    ``registry.flash_bwd_tiling`` picks, within the VMEM budget."""
+    t = _compile_flash_bwd(one_chip, shape, dtype)
+    assert t.block_q >= 128 and t.block_k >= 128
+    assert registry.flash_bwd_vmem_bytes(
+        t.block_q, t.block_k, shape[3],
+        jnp.dtype(dtype).itemsize) <= registry.FLASH_VMEM_BUDGET
+
+
+@pytest.mark.parametrize("shape,dtype", PICKED_SHAPES)
 def test_flash_attention_compiles_at_picked_tiling(one_chip, shape, dtype):
     """The blocks ``registry.flash_tiling`` picks compile (Mosaic refuses
     a kernel whose VMEM overflows its scoped limit)."""
@@ -110,8 +148,9 @@ def test_flash_attention_compiles_at_picked_tiling(one_chip, shape, dtype):
 
 def test_flash_attention_compiles_at_every_config_shape(one_chip):
     """Every config's fused attention at its train and prefill lengths
-    (whisper's encoder and decoder at half of them): where the tiling
-    differs from the parent's 128-blocks, it compiles."""
+    (whisper's encoder and decoder at half of them), forward and
+    backward: where the tiling differs from the 128-blocks, it
+    compiles."""
     from repro.configs import ARCH_IDS, cells, get_config
     shapes = set()
     for arch in ARCH_IDS:
@@ -122,12 +161,16 @@ def test_flash_attention_compiles_at_every_config_shape(one_chip):
             if shape.kind != "decode":
                 n = shape.seq_len // (2 if cfg.is_encoder_decoder else 1)
                 shapes.add((n, cfg.resolved_head_dim))
+    base = lambda S: (registry.pick_block(S, 128),) * 2     # noqa: E731
     for S, hd in sorted(shapes):
         for causal in (True, False):
             t = registry.flash_tiling(S, S, hd, causal, 2)
-            if (t.block_q, t.block_k) == (registry.pick_block(S, 128),) * 2:
-                continue
-            _compile_flash(one_chip, (1, 2, S, hd), jnp.bfloat16, causal)
+            if (t.block_q, t.block_k) != base(S):
+                _compile_flash(one_chip, (1, 2, S, hd), jnp.bfloat16, causal)
+            t = registry.flash_bwd_tiling(S, S, hd, causal, 2)
+            if (t.block_q, t.block_k) != base(S):
+                _compile_flash_bwd(one_chip, (1, 2, S, hd), jnp.bfloat16,
+                                   causal)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -163,8 +206,10 @@ def test_sharded_attention_site_lowers_through_shard_map(mesh22):
     compiled = lowered.compile()
     assert _holds_kernel(compiled)
     # per-device kernel: each of the 4 devices sees 2 of 4 rows and 7 of
-    # 14 heads
-    assert "bf16[2,7,256,64]" in compiled.as_text()
+    # 14 heads; the backward runs as the Pallas passes there too
+    text = compiled.as_text()
+    assert "bf16[2,7,256,64]" in text
+    assert _holds_backward(text)
 
 
 def test_fused_train_step_plan_compiles_on_2x2(mesh22, monkeypatch):
@@ -210,8 +255,37 @@ def test_phi3_mini_2x2_cell_plan_fits_a_v5e_chip(mesh22, monkeypatch):
             mix["mesh"], mix["search"]) == (
         16, 8, 2048, [2, 2], {"backend": "mcts", "seed": 0})
     tc = harness.TrainCell(conf, mix, list(mesh22.devices.flat))
-    assert tc.plan.evaluations > 0
-    assert [(r["impl"], r["sharded"]) for r in tc.plan.kernel_sites] == \
-        [("pallas", True)] * 2
+    ev = tc.plan_evidence()
+    # the searched plan of record: the cost model prices the backward as
+    # the reference, so the Pallas backward moves nothing here
+    assert (ev["plan_hash"], ev["evaluations"]) == ("f1100235c4d06a6b", 1405)
+    assert ev["cost"] == pytest.approx(0.438854, abs=1e-6)
+    assert [(r["impl"], r["sharded"], r["bwd_impl"])
+            for r in tc.plan.kernel_sites] == [("pallas", True, "pallas")] * 2
     assert tc.kernels_shard_mapped
+    assert _holds_backward(tc.compiled.as_text())
+    assert tc.memory_analysis()["step_bytes"] < 16 * 2**30
+
+
+def test_qwen2_05b_cell_plan_and_backward_compile_for_a_v5e_chip(
+        topo, monkeypatch):
+    """The benchmark's ``qwen2_05b.train.s1k`` step as its harness
+    builds it keeps its plan and compiles with the Pallas backward, at
+    the blocks its site record names."""
+    from perfbench import catalog, harness
+
+    monkeypatch.setattr(ops, "default_interpret", lambda: False)
+    bench = catalog.benchmark()
+    cell = catalog.workload(bench, "qwen2_05b.train.s1k")
+    mix = catalog.traffic(cell["traffic"])
+    tc = harness.TrainCell(catalog.config(bench, cell["config"]), mix,
+                           [topo.devices[0]])
+    assert tc.plan_evidence()["plan_hash"] == "4882248af0175949"
+    t = registry.flash_bwd_tiling(1024, 1024, 64, True, 2)
+    for r in tc.plan.kernel_sites:
+        assert (r["bwd_impl"], r["bwd_block_q"], r["bwd_block_k"],
+                r["bwd_tiles_computed"], r["bwd_tiles_total"]) == (
+            "pallas", t.block_q, t.block_k, t.tiles_computed,
+            t.tiles_total)
+    assert _holds_backward(tc.compiled.as_text())
     assert tc.memory_analysis()["step_bytes"] < 16 * 2**30
