@@ -26,10 +26,6 @@ Sections:
           mesh — per-phase analysis time, dense vs incremental
           evals/sec, real search, vectorized-analysis exactness oracle
           (writes BENCH_fullscale.json); opt-in, minutes of wall time.
-- guidance: learned-guidance transfer benchmark — train the policy/value
-          model on 8 zoo architectures, evaluate guided-vs-unguided
-          MCTS on the held-out archs and both full-size programs
-          (writes BENCH_guidance.json); opt-in, minutes of wall time.
 - kernels: Pallas kernel microbenchmarks (interpret mode) vs jnp oracle;
           as an explicit section it also runs the kernel-aware
           partitioning benchmark — fused-op trace, joint kernel+sharding
@@ -225,12 +221,9 @@ def main() -> None:
     ap.add_argument("--section", default="all",
                     choices=["all", "fig8", "fig10", "nda", "search",
                              "zoo", "measure", "meshsearch", "fullscale",
-                             "guidance", "kernels"])
+                             "kernels"])
     ap.add_argument("--models", default=",".join(MODELS))
     ap.add_argument("--search-out", default="BENCH_search.json")
-    ap.add_argument("--search-guided", action="store_true",
-                    help="add guided-vs-unguided MCTS rows on the "
-                         "full-size programs to the search section")
     ap.add_argument("--zoo-out", default="BENCH_zoo.json")
     ap.add_argument("--zoo-mesh", default="4x2")
     ap.add_argument("--zoo-plan-store", default="",
@@ -251,10 +244,6 @@ def main() -> None:
     ap.add_argument("--kernels-no-measure", action="store_true",
                     help="kernels section: skip the measured-execution "
                          "subprocesses (static record only)")
-    ap.add_argument("--guidance-out", default="BENCH_guidance.json")
-    ap.add_argument("--guidance-smoke", action="store_true",
-                    help="guidance CI mode: two reduced configs, tiny "
-                         "model, in-distribution eval only")
     args = ap.parse_args()
     models = tuple(args.models.split(","))
     print("name,us_per_call,derived")
@@ -266,8 +255,7 @@ def main() -> None:
         nda_latency()
     if args.section in ("all", "search"):
         from benchmarks import search_throughput
-        search_throughput.run(out=args.search_out,
-                              guided=args.search_guided)
+        search_throughput.run(out=args.search_out)
     if args.section in ("all", "zoo"):
         zoo_sweep(out=args.zoo_out, mesh=args.zoo_mesh,
                   plan_store=args.zoo_plan_store or None)
@@ -282,9 +270,6 @@ def main() -> None:
         from benchmarks import fullscale
         fullscale.run(out=args.fullscale_out, mesh=args.fullscale_mesh,
                       smoke=args.fullscale_smoke)
-    if args.section == "guidance":      # opt-in: trains + full programs
-        from benchmarks import guidance
-        guidance.run(out=args.guidance_out, smoke=args.guidance_smoke)
     if args.section in ("all", "kernels"):
         kernel_micro()
     if args.section == "kernels":       # opt-in: executes real programs

@@ -11,13 +11,6 @@ Compares three evaluation paths on the paper's transformer config:
   second including transposition-cache hits, plus the best cost found (the
   regression anchor: incremental evaluation is exact, so best-cost must not
   degrade).
-- **guided** (opt-in, ``guided=True`` / ``--search-guided``): unguided vs
-  policy-guided MCTS on the full-size production programs — the
-  throughput cost of the prior computation (featurizer + MLP forward per
-  fresh node) next to the best cost each search reached.  A small model
-  is trained on traces collected from the same program right before the
-  timed run, so the row measures guidance overhead, not transfer quality
-  (that is ``benchmarks/guidance.py``).
 
 Emits the repo's ``name,us_per_call,derived`` CSV rows and writes
 ``BENCH_search.json``.
@@ -25,7 +18,6 @@ Emits the repo's ``name,us_per_call,derived`` CSV rows and writes
 
 from __future__ import annotations
 
-import dataclasses
 import json
 import random
 import time
@@ -65,66 +57,9 @@ def _random_walks(actions, *, n_walks: int, depth: int, seed: int):
     return walks
 
 
-def _guided_rows(models=FULL_MODELS, *,
-                 mcts_cfg: MCTSConfig | None = None) -> list[dict]:
-    """Unguided-vs-guided MCTS throughput on the full-size programs."""
-    from repro.configs import get_config
-    from repro.core.partitioner import analyze
-    from repro.guidance import (GuidanceSpec, TraceStore, train_model,
-                                uniform_guidance)
-    from repro.launch.specs import step_and_inputs
-    from repro.launch.zoo import ZOO_SHAPE_FULL
-    import tempfile
-
-    cfg = mcts_cfg or MCTSConfig(rounds=4, trajectories_per_round=16)
-    rows: list[dict] = []
-    for name in models:
-        fn, args, _ = step_and_inputs(get_config(name), ZOO_SHAPE_FULL)
-        art = analyze(fn, args, {})
-        cm = CostModel(art.prog, art.nda, art.analysis, FULL_MESH,
-                       HardwareSpec())
-        actions = build_action_space(art.nda, art.analysis, FULL_MESH,
-                                     min_dims=10)
-        # train a small model on this very program (overhead measure,
-        # not a transfer eval) — one deeper collection run suffices
-        with tempfile.TemporaryDirectory() as d:
-            store = TraceStore(d)
-            spec = uniform_guidance(collector=store, tag=name)
-            MCTS(IncrementalEvaluator(cm), actions,
-                 dataclasses.replace(cfg, seed=7, rounds=6,
-                                     trajectories_per_round=24,
-                                     guidance=spec)).search()
-            model_pv, _ = train_model(store.load_all(), epochs=120,
-                                      seed=0)
-        guide = GuidanceSpec(model=model_pv)
-
-        row = {"model": name, "ops": len(art.prog.ops),
-               "actions": len(actions)}
-        for label, guidance in (("unguided", None), ("guided", guide)):
-            ev = IncrementalEvaluator(cm)
-            agent = MCTS(ev, actions,
-                         dataclasses.replace(cfg, guidance=guidance))
-            t0 = time.perf_counter()
-            res = agent.search()
-            secs = time.perf_counter() - t0
-            eps = res.evaluations / max(secs, 1e-12)
-            row[label] = {"best_cost": res.best_cost,
-                          "evaluations": res.evaluations,
-                          "seconds": secs, "states_per_s": eps}
-            _row(f"search.mcts_{label}.{name}", secs * 1e6,
-                 f"states_per_s={eps:.1f};best_cost={res.best_cost:.4f};"
-                 f"evaluations={res.evaluations}")
-        row["throughput_ratio"] = (row["guided"]["states_per_s"] /
-                                   max(row["unguided"]["states_per_s"],
-                                       1e-12))
-        rows.append(row)
-    return rows
-
-
 def run(model: str = "t2b", *, n_walks: int = 24, depth: int = 10,
         dense_sample: int = 40, seed: int = 0,
         mcts_cfg: MCTSConfig | None = None,
-        guided: bool = False,
         out: str | None = "BENCH_search.json") -> dict:
     from benchmarks import common
     art, _ = common.artifacts_for(model)
@@ -183,8 +118,6 @@ def run(model: str = "t2b", *, n_walks: int = 24, depth: int = 10,
     _row(f"search.mcts.{model}", t_search * 1e6,
          f"states_per_s={search_eps:.1f};best_cost={res.best_cost:.4f};"
          f"evaluations={res.evaluations}")
-    if guided:          # opt-in: analyzes the full production programs
-        record["guided_fullscale"] = _guided_rows()
     if out:
         with open(out, "w") as f:
             json.dump(record, f, indent=2)

@@ -97,13 +97,6 @@ class Request:
             targets.
         constraints: ``Pin`` / ``Replicate`` / ``Forbid`` constraints
             the plan must satisfy.
-        guidance: optional ``repro.guidance.GuidanceSpec`` injected into
-            MCTS (and portfolio-member MCTS) search configs that carry
-            none of their own.  Deliberately **not** part of the plan
-            store key: guidance changes how fast the search finds a
-            plan, not what a valid plan is — which also means a plan
-            store *hit* returns before any search runs, so neither
-            priors nor trace collection fire on cached requests.
     """
 
     mesh: MeshSpec
@@ -113,7 +106,6 @@ class Request:
     min_dims: int = DEFAULT_MIN_DIMS
     logical_axes: Any = None
     constraints: tuple[Constraint, ...] = ()
-    guidance: Any = None
 
     def __post_init__(self) -> None:
         """Normalize mutable spellings (constraint lists) to tuples."""
@@ -146,31 +138,6 @@ class Request:
         return {"min_dims": self.min_dims,
                 "logical_axes": self.flat_logical_axes(),
                 "constraints": self.constraints}
-
-
-def _with_guidance(engine: SearchBackend, config: Any, guidance: Any) -> Any:
-    """Inject ``guidance`` into a search config for ``engine``.
-
-    MCTS configs (and portfolio configs, whose members inject further
-    down) gain the spec unless they already carry one; other backends
-    ignore guidance entirely.  ``guidance=None`` returns ``config``
-    untouched, preserving the default-off bit-identity contract.
-    """
-    if guidance is None:
-        return config
-    if engine.name == "mcts":
-        from repro.core.mcts import MCTSConfig
-        if config is None:
-            return MCTSConfig(guidance=guidance)
-        if getattr(config, "guidance", None) is None:
-            return dataclasses.replace(config, guidance=guidance)
-    elif engine.name == "portfolio":
-        from repro.core.portfolio import PortfolioConfig
-        if config is None:
-            return PortfolioConfig(guidance=guidance)
-        if getattr(config, "guidance", None) is None:
-            return dataclasses.replace(config, guidance=guidance)
-    return config
 
 
 @dataclasses.dataclass
@@ -371,11 +338,9 @@ class Session:
             root = cs.root_state()
         engine = get_backend(request.backend)
         evaluator = IncrementalEvaluator(cm, constraints=cs)
-        search_config = _with_guidance(engine, request.search_config,
-                                       request.guidance)
         with span("search", phases):
-            result = engine.search(evaluator, actions, search_config,
-                                   root=root)
+            result = engine.search(evaluator, actions,
+                                   request.search_config, root=root)
         elapsed = time.perf_counter() - t0
 
         eval_stats = evaluator.stats.as_dict()

@@ -1093,23 +1093,6 @@ class CostModel:
         """C(s) = RT(s) + MP(s) — paper §4.5."""
         return self.cost_from_breakdown(self.evaluate(state))
 
-    def ops_touching_color(self, color: int) -> int:
-        """How many program ops carry a cost-row dependency on ``color``.
-
-        A static (mesh- and hardware-independent) quantity from the
-        ``_color_ops`` table: the ops whose cost rows must be re-priced
-        when the color's sharding changes.  The guidance featurizer uses
-        it as a program-scale-free "how much of the program does this
-        color span" action feature (``repro.guidance.features``).
-
-        Args:
-            color: NDA color id.
-
-        Returns:
-            The op count (0 for unknown colors).
-        """
-        return len(self._color_ops.get(color, _EMPTY))
-
     # -- calibration features ------------------------------------------------
 
     def state_features(self, state: ShardingState) -> dict:

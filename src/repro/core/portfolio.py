@@ -81,18 +81,12 @@ class PortfolioConfig:
             best feasible cost before the remaining members are cancelled.
         rel_tol: relative cost decrease that counts as an improvement for
             the plateau detector.
-        guidance: optional ``repro.guidance.GuidanceSpec`` injected into
-            every MCTS member whose own config carries none (explicitly
-            guided member configs are left alone); non-MCTS members
-            ignore it.  ``None`` (default) leaves every member exactly
-            as before.
     """
 
     members: tuple[PortfolioMember, ...] = ()
     max_workers: int | None = None
     patience: int = 2
     rel_tol: float = 0.01
-    guidance: Any = None
 
 
 @dataclasses.dataclass
@@ -152,23 +146,13 @@ def default_portfolio(seeds: tuple[int, ...] = (0, 1, 2)
     return tuple(members)
 
 
-def _member_config(member: PortfolioMember, engine: SearchBackend,
-                   guidance: Any = None):
-    """Resolve the member's backend config, injecting the seed for MCTS.
-
-    A portfolio-level ``guidance`` spec is attached to MCTS members that
-    do not already carry their own (``dataclasses.replace``, so shared
-    member configs are never mutated).
-    """
+def _member_config(member: PortfolioMember, engine: SearchBackend):
+    """Resolve the member's backend config, injecting the seed for MCTS."""
     if member.config is not None:
-        cfg = member.config
-        if guidance is not None and engine.name == "mcts" and \
-                getattr(cfg, "guidance", None) is None:
-            cfg = dataclasses.replace(cfg, guidance=guidance)
-        return cfg
+        return member.config
     if engine.name == "mcts":
         from repro.core.mcts import MCTSConfig
-        return MCTSConfig(seed=member.seed, guidance=guidance)
+        return MCTSConfig(seed=member.seed)
     return None
 
 
@@ -221,8 +205,7 @@ class PortfolioBackend(SearchBackend):
             ev = IncrementalEvaluator(
                 cm, constraints=getattr(evaluator, "constraints", None))
             t0 = time.perf_counter()
-            res = engine.search(ev, actions,
-                                _member_config(member, engine, cfg.guidance),
+            res = engine.search(ev, actions, _member_config(member, engine),
                                 root)
             return res, time.perf_counter() - t0
 
@@ -305,5 +288,5 @@ class PortfolioBackend(SearchBackend):
             best_state=win.best_state, best_cost=win.best_cost,
             best_actions=win.best_actions, rounds_run=completed,
             evaluations=total_evals, history=win.history,
-            curve=win.curve, members=ordered, early_stopped=stop_issued,
+            members=ordered, early_stopped=stop_issued,
             winner=members[best_idx].name)

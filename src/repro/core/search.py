@@ -46,10 +46,6 @@ class SearchResult:
         evaluations: cost queries issued, transposition-cache hits
             included.
         history: best-known cost after each round.
-        curve: eval-indexed improvement curve — ``(evaluations,
-            best_cost)`` appended every time the best-known cost drops
-            (empty for backends that do not record it).  This is what
-            "evals-to-match" guidance comparisons are computed from.
     """
 
     best_state: ShardingState
@@ -61,8 +57,6 @@ class SearchResult:
     # from-base evaluations — is in the evaluator's EvalStats).
     evaluations: int
     history: list[float]
-    curve: list[tuple[int, float]] = dataclasses.field(
-        default_factory=list)
 
 
 class SearchBackend:

@@ -150,8 +150,7 @@ def run_model(arch: str, mesh: MeshSpec, *,
               full: bool = False,
               min_dims: int = 10,
               capture: dict | None = None,
-              profile: bool = False,
-              guidance=None) -> dict:
+              profile: bool = False) -> dict:
     """Auto-partition one zoo model and summarize the outcome.
 
     Args:
@@ -170,10 +169,6 @@ def run_model(arch: str, mesh: MeshSpec, *,
         profile: trace allocations with ``tracemalloc`` and attach a
             ``row["profile"]`` wall/alloc breakdown per pipeline stage
             (roughly 2x slower — a diagnosis mode, not a benchmark).
-        guidance: optional ``repro.guidance.GuidanceSpec`` attached to
-            the request (re-tagged with ``arch`` so collected traces are
-            attributable).  A plan-store *hit* skips the search, so
-            neither priors nor trace collection fire on cached rows.
 
     Returns:
         A flat JSON-friendly result row; ``row["status"]`` is ``"ok"`` or
@@ -204,12 +199,10 @@ def run_model(arch: str, mesh: MeshSpec, *,
             _, analysis_peak = tracemalloc.get_traced_memory()
             tracemalloc.reset_peak()
         t0 = time.perf_counter()
-        if guidance is not None:
-            guidance = dataclasses.replace(guidance, tag=arch)
         request = Request(
             mesh=mesh, hw=hw, backend=backend,
             search_config=search_config, min_dims=min_dims,
-            logical_axes=names, guidance=guidance)
+            logical_axes=names)
         plan = sess.partition(request)
         if profile:
             search_wall = time.perf_counter() - t0
@@ -271,8 +264,7 @@ def run_zoo(mesh: MeshSpec, *, archs: tuple[str, ...] | None = None,
             min_dims: int = 10,
             verbose: bool = True,
             captures: dict | None = None,
-            profile: bool = False,
-            guidance=None) -> dict:
+            profile: bool = False) -> dict:
     """Sweep the whole config zoo on one mesh.
 
     Args:
@@ -290,8 +282,6 @@ def run_zoo(mesh: MeshSpec, *, archs: tuple[str, ...] | None = None,
         captures: optional dict collecting per-arch ``(session, request,
             plan)`` for the ``--measure`` pass (see ``run_model``).
         profile: per-model wall/alloc breakdown (see ``run_model``).
-        guidance: optional ``repro.guidance.GuidanceSpec`` shared by all
-            models (re-tagged per arch; see ``run_model``).
 
     Returns:
         The sweep record: ``{"mesh", "shape", "backend", "results": [...],
@@ -309,7 +299,7 @@ def run_zoo(mesh: MeshSpec, *, archs: tuple[str, ...] | None = None,
         row = run_model(arch, mesh, shape=shape, hw=hw, backend=backend,
                         search_config=search_config, plan_store=plan_store,
                         full=full, min_dims=min_dims, capture=captures,
-                        profile=profile, guidance=guidance)
+                        profile=profile)
         rows.append(row)
         if verbose:
             if row["status"] == "ok":
@@ -327,8 +317,6 @@ def run_zoo(mesh: MeshSpec, *, archs: tuple[str, ...] | None = None,
                   "global_batch": shape.global_batch, "kind": shape.kind},
         "backend": backend,
         "full_configs": full,
-        "guided": bool(guidance is not None
-                       and guidance.model is not None),
         "results": rows,
         "cache": plan_store.stats.as_dict() if plan_store is not None
         else None,
@@ -963,16 +951,6 @@ def main(argv: list[str] | None = None) -> dict:
                     help="price plans with the calibrated HardwareSpec "
                          "saved in the plan store by a previous "
                          "--measure run")
-    ap.add_argument("--guided", default=None, metavar="MODEL.json",
-                    help="guide the MCTS portfolio members with a "
-                         "trained policy/value model (see python -m "
-                         "repro.launch.guide train); cached plan-store "
-                         "hits bypass the search and thus the guidance")
-    ap.add_argument("--collect-traces", default=None, metavar="DIR",
-                    help="persist a SearchTrace per MCTS search into "
-                         "DIR (training data for repro.launch.guide); "
-                         "combine with --no-plan-store so cache hits "
-                         "don't skip the searches")
     ap.add_argument("--co-search", type=int, default=None, metavar="N",
                     help="mesh-shape co-search: enumerate every mesh "
                          "factorization of N devices (instead of "
@@ -1007,17 +985,6 @@ def main(argv: list[str] | None = None) -> dict:
     if args.backend == "portfolio":
         search_config = zoo_portfolio(seeds=args.seeds,
                                       workers=args.workers or 2)
-
-    guidance = None
-    if args.guided is not None or args.collect_traces is not None:
-        from repro.guidance import (TraceStore, load_guidance,
-                                    uniform_guidance)
-        collector = (TraceStore(args.collect_traces)
-                     if args.collect_traces is not None else None)
-        if args.guided is not None:
-            guidance = load_guidance(args.guided, collector=collector)
-        else:
-            guidance = uniform_guidance(collector=collector)
 
     if args.archs is not None:                  # explicit wins, always
         archs = tuple(args.archs.split(","))
@@ -1072,7 +1039,7 @@ def main(argv: list[str] | None = None) -> dict:
                      backend=args.backend, search_config=search_config,
                      plan_store=store, full=args.full,
                      min_dims=args.min_dims, captures=captures,
-                     profile=args.profile, guidance=guidance)
+                     profile=args.profile)
     if profiler is not None:
         profiler.disable()
         print(format_profile(record["results"]))
@@ -1096,9 +1063,6 @@ def main(argv: list[str] | None = None) -> dict:
         line += (f" | plan store: {s.hits} hits / {s.misses} misses "
                  f"({args.plan_store})")
     print(line)
-    if guidance is not None and guidance.collector is not None:
-        print(f"trace store: {len(guidance.collector)} trace(s) in "
-              f"{args.collect_traces}")
 
     out = pathlib.Path(args.out)
     out.write_text(json.dumps(record, indent=2))

@@ -28,12 +28,6 @@ DOC_GATED_FILES = [
     "src/repro/launch/measure.py",
     "src/repro/core/mesh_search.py",
     "src/repro/core/verify.py",
-    "src/repro/guidance/features.py",
-    "src/repro/guidance/trace.py",
-    "src/repro/guidance/model.py",
-    "src/repro/guidance/spec.py",
-    "src/repro/guidance/evaluate.py",
-    "src/repro/launch/guide.py",
     "src/repro/kernels/registry.py",
     "src/repro/kernels/ops.py",
     "src/repro/kernels/flash_attention.py",
