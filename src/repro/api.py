@@ -350,6 +350,8 @@ class Session:
                 "early_stopped": result.early_stopped,
                 "members": [m.as_dict() for m in result.members],
             }
+        if result.descent is not None:
+            eval_stats["descent"] = result.descent
         # the plan keeps this dict, so "build_plan" lands in it as well
         eval_stats["phase_seconds"] = phases
         with span("build_plan", phases):

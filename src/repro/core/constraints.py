@@ -231,12 +231,18 @@ class ConstraintSet:
         penalty: cost added per violation by
             :meth:`penalty_for` — large enough that any violating state
             is strictly worse than every feasible one.
+        pinned_dims: ``(value id, dim, axes)`` of every program input
+            dim a :class:`Pin` shards.  A state can carry a pinned color
+            and still leave such a dim without its axes: an axis shards
+            at most one dim of a tensor, and an earlier dim of another
+            color may take it.
     """
 
     pinned: tuple[tuple[int, tuple[str, ...]], ...] = ()
     forbidden: tuple[tuple[int, tuple[str, ...]], ...] = ()
     source: tuple = ()
     penalty: float = 1e6
+    pinned_dims: tuple[tuple[int, int, tuple[str, ...]], ...] = ()
 
     def root_state(self) -> "ShardingState":
         """The seeded search root carrying every pinned assignment."""
@@ -289,11 +295,38 @@ class ConstraintSet:
                                f"but present in state")
         return out
 
-    def penalty_for(self, state: "ShardingState") -> float:
+    def unsharded_pins(self, cm, state: "ShardingState") -> int:
+        """How many of ``pinned_dims`` the specs projected from
+        ``state`` leave without exactly their pinned axes.
+
+        Args:
+            cm: the ``CostModel`` whose projection the plan will use.
+            state: canonical sharding state to check.
+
+        Returns:
+            The count of such dims (0 when the specs keep every pin).
+        """
+        if not self.pinned_dims:
+            return 0
+        color_axes = dict(state.color_axes)
+        suppressed = cm.suppressed_for(state.bits)
+        site_axes: dict[int, list] = {}
+        n = 0
+        for vid, dim, axes in self.pinned_dims:
+            got = site_axes.get(vid)
+            if got is None:
+                got = site_axes[vid] = cm.site_axes(
+                    cm.nda.def_site[vid], color_axes, suppressed)
+            n += tuple(got[dim]) != axes
+        return n
+
+    def penalty_for(self, state: "ShardingState", cm) -> float:
         """Infeasibility penalty of ``state``: ``penalty`` per violation.
 
         Args:
             state: canonical sharding state to check.
+            cm: the ``CostModel`` whose projected specs the pinned input
+                dims are checked on (:meth:`unsharded_pins`).
 
         Returns:
             0.0 for satisfying states; a cost large enough to dominate
@@ -301,7 +334,8 @@ class ConstraintSet:
         """
         if not self.pinned and not self.forbidden:
             return 0.0
-        return self.penalty * len(self.violations(state))
+        return self.penalty * (len(self.violations(state)) +
+                               self.unsharded_pins(cm, state))
 
 
 def _resolve_logical_dims(name: str, prog: "Program",
@@ -352,6 +386,7 @@ def compile_constraints(constraints: Sequence[Constraint],
     axis_size = dict(zip(mesh.axes, mesh.sizes))
     names = _logical_names(logical_axes)
     pinned: dict[int, tuple[str, ...]] = {}
+    pinned_dims: list[tuple[int, int, tuple[str, ...]]] = []
     pin_src: dict[int, str] = {}
     forbidden: dict[int, set[str]] = {}
 
@@ -410,6 +445,8 @@ def compile_constraints(constraints: Sequence[Constraint],
                     check_divides(vid, d, axes, what)
                     pin_color(nda.color(nda.def_site[vid].dims[d]), axes,
                               what)
+                    if axes:
+                        pinned_dims.append((vid, d, axes))
             else:
                 idxs = match_paths(c.target, prog.input_paths)
                 if not idxs:
@@ -437,6 +474,8 @@ def compile_constraints(constraints: Sequence[Constraint],
                         check_divides(vid, d, axes, what)
                         pin_color(nda.color(nda.def_site[vid].dims[d]),
                                   axes, what)
+                        if axes:
+                            pinned_dims.append((vid, d, axes))
         elif isinstance(c, Replicate):
             what = f"Replicate({c.target!r})"
             for vid, d in target_dims(c.target, what):
@@ -461,7 +500,7 @@ def compile_constraints(constraints: Sequence[Constraint],
         pinned=tuple(sorted(pinned.items())),
         forbidden=tuple(sorted((c, tuple(sorted(a)))
                                for c, a in forbidden.items())),
-        source=tuple(constraints))
+        source=tuple(constraints), pinned_dims=tuple(pinned_dims))
 
 
 def check_plan(plan, constraints: Sequence[Constraint]) -> list[str]:

@@ -70,7 +70,8 @@ class IncrementalEvaluator:
 
     ``constraints`` (a compiled ``repro.core.constraints.ConstraintSet``)
     marks violating states infeasible: ``paper_cost`` /
-    ``paper_cost_child`` add the set's penalty per violated pin/forbid,
+    ``paper_cost_child`` add the set's penalty per violated pin/forbid
+    and per pinned input dim the projected specs leave unsharded,
     so even a backend that synthesizes states outside the pruned action
     space can never prefer a constraint-violating plan.  Breakdowns
     (``evaluate``) stay exact — the penalty is a search-cost concern.
@@ -160,7 +161,7 @@ class IncrementalEvaluator:
         """
         cost = self.cm.cost_from_breakdown(self.evaluate(state))
         if self.constraints is not None:
-            cost += self.constraints.penalty_for(state)
+            cost += self.constraints.penalty_for(state, self.cm)
         return cost
 
     def paper_cost_child(self, parent: ShardingState, action: Action
@@ -178,7 +179,7 @@ class IncrementalEvaluator:
         state, bd = self.child(parent, action)
         cost = self.cm.cost_from_breakdown(bd)
         if self.constraints is not None:
-            cost += self.constraints.penalty_for(state)
+            cost += self.constraints.penalty_for(state, self.cm)
         return state, cost
 
     # -- internals -----------------------------------------------------------

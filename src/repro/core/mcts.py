@@ -12,6 +12,11 @@ Faithful to the paper's adaptations of standard UCT:
   length so shorter action sequences with equal cost are preferred (§4.1).
 - Trajectories end on a explicit *stop* action or at ``max_depth`` (30 in
   the paper).
+- **Final descent** (not in the paper): the tree returns only states on
+  its paths or at the end of a random playout, so its answer need not be
+  a local minimum.  After the last round, a steepest descent from the
+  best state takes the cheapest single action while it gains more than
+  the short-trajectory incentive ``length_penalty``.
 
 Evaluation runs through ``IncrementalEvaluator``: every action application
 during tree walk and playout costs the child *incrementally* from its
@@ -169,9 +174,31 @@ class MCTS:
                     break           # paper: stop when a round fails to improve
             else:
                 stale = 0
-        actions = recover_actions(best_state)
-        return SearchResult(best_state, best_cost, actions, rounds_run,
-                            self.evaluations, history)
+        descended, cost, steps, evals = self._descend(best_state, best_cost)
+        history.append(cost)
+        descent = {"steps": steps, "evaluations": evals,
+                   "cost_before": best_cost, "cost_after": cost}
+        return SearchResult(descended, cost, recover_actions(descended),
+                            rounds_run, self.evaluations, history, descent)
+
+    def _descend(self, state: ShardingState, cost: float
+                 ) -> tuple[ShardingState, float, int, int]:
+        """Steepest descent from ``state``: scan every valid action and
+        move to the cheapest child while it is cheaper by more than
+        ``length_penalty``.  Returns the final state, its cost, the
+        steps taken and the cost queries spent."""
+        evals0 = self.evaluations
+        steps = 0
+        while True:
+            best = None
+            for action in valid_actions(self.actions, state):
+                child, c = self._cost_child(state, action)
+                if best is None or c < best[1]:
+                    best = (child, c)
+            if best is None or cost - best[1] <= self.cfg.length_penalty:
+                return state, cost, steps, self.evaluations - evals0
+            state, cost = best
+            steps += 1
 
 
 class MCTSBackend(SearchBackend):

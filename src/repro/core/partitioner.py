@@ -109,9 +109,11 @@ class ShardingPlan:
         num_resolution_bits: supergroup resolution bits (paper §3.6).
         backend: name of the search backend that produced the plan.
         eval_stats: evaluator work counters (cache hits / incremental /
-            from-base evaluations) and, under ``"phase_seconds"``, the
+            from-base evaluations); under ``"phase_seconds"``, the
             wall seconds of the partition phases (``cost_model``,
-            ``actions``, ``search``, ``build_plan``).
+            ``actions``, ``search``, ``build_plan``); and under
+            ``"descent"``, when the search ran MCTS, its final descent
+            (``SearchResult.descent``).
         fingerprint: deterministic program fingerprint
             (:func:`repro.core.ir.program_fingerprint`) when known.
         cached: True when the plan was served from a

@@ -117,8 +117,8 @@ class PortfolioResult(SearchResult):
     """A :class:`SearchResult` plus per-member outcomes.
 
     ``rounds_run`` counts completed members; ``evaluations`` sums cost
-    queries across all completed members; ``history`` is the winning
-    member's cost history.
+    queries across all completed members; ``history`` and ``descent``
+    are the winning member's.
     """
 
     members: list[MemberOutcome] = dataclasses.field(default_factory=list)
@@ -288,5 +288,5 @@ class PortfolioBackend(SearchBackend):
             best_state=win.best_state, best_cost=win.best_cost,
             best_actions=win.best_actions, rounds_run=completed,
             evaluations=total_evals, history=win.history,
-            members=ordered, early_stopped=stop_issued,
+            descent=win.descent, members=ordered, early_stopped=stop_issued,
             winner=members[best_idx].name)

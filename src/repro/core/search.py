@@ -45,7 +45,13 @@ class SearchResult:
             depths, portfolio members completed).
         evaluations: cost queries issued, transposition-cache hits
             included.
-        history: best-known cost after each round.
+        history: best-known cost after each round (MCTS adds one entry
+            for its final descent).
+        descent: MCTS's final descent, ``{"steps", "evaluations",
+            "cost_before", "cost_after"}``: the actions it accepted, the
+            cost queries it spent (counted in ``evaluations`` too), and
+            the best cost before and after it.  ``None`` for backends
+            that run none.
     """
 
     best_state: ShardingState
@@ -57,6 +63,7 @@ class SearchResult:
     # from-base evaluations — is in the evaluator's EvalStats).
     evaluations: int
     history: list[float]
+    descent: dict | None = None
 
 
 class SearchBackend:

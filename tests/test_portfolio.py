@@ -72,6 +72,23 @@ class TestPortfolioBackend:
         assert res.best_cost == pytest.approx(min(solo))
         assert res.best_cost < 1.0
 
+    def test_mcts_winner_carries_its_descent(self, search_inputs):
+        """An MCTS member runs its final descent inside the portfolio,
+        and the winner's descent is the portfolio's; beam runs none."""
+        cm, actions = search_inputs
+        mcts = PortfolioMember("mcts", seed=3, config=MCTSConfig(
+            seed=3, rounds=3, trajectories_per_round=12))
+        solo = get_backend("mcts").search(IncrementalEvaluator(cm), actions,
+                                          mcts.config)
+        res = PortfolioBackend().search(
+            IncrementalEvaluator(cm), actions,
+            PortfolioConfig(members=(mcts,), max_workers=1))
+        assert res.descent == solo.descent
+        assert res.descent["cost_after"] == res.best_cost
+        beam = get_backend("beam").search(IncrementalEvaluator(cm), actions,
+                                          BeamConfig(patience=1))
+        assert beam.descent is None
+
     def test_member_outcomes_recorded(self, search_inputs):
         cm, actions = search_inputs
         cfg = PortfolioConfig(members=(
